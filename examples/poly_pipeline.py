@@ -18,6 +18,7 @@ import jax.numpy as jnp         # noqa: E402
 import numpy as np              # noqa: E402
 
 from repro.core import pipeline  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def show(kinds, n_items):
@@ -36,7 +37,7 @@ def main():
     show(["full", "pointwise"], 6)  # encoder edge degenerates to barrier
 
     print("\n== execution on a 4-device stage mesh ==")
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = make_mesh((4,), ("stage",))
     n_stages, n_items, dim = 4, 8, 64
     rng = np.random.default_rng(0)
     w = jnp.asarray(rng.normal(size=(n_stages, dim, dim)) / np.sqrt(dim),

@@ -218,6 +218,17 @@ def all_archs() -> List[str]:
     return sorted(_REGISTRY)
 
 
+def depth_cut(name: str, n_layers: int) -> ArchConfig:
+    """Published config ``name`` at its published widths, cut to
+    ``n_layers`` layers (whole periods) — what one chip can hold."""
+    cfg = get_arch(name)
+    plen = len(cfg.layer_period or "A")
+    if not 0 < n_layers <= cfg.n_layers or n_layers % plen:
+        raise ValueError(f"{name}: cannot cut {cfg.n_layers} layers to "
+                         f"{n_layers} (period {plen})")
+    return dataclasses.replace(cfg, n_layers=n_layers)
+
+
 def smoke_config(name: str) -> ArchConfig:
     """Reduced same-family config for CPU smoke tests."""
     cfg = get_arch(name)

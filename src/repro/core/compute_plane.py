@@ -22,11 +22,13 @@ Three backends:
     "conductances" with per-row scales (the analog-programming model, paper
     §3.5), activations streamed through the MXU; ``dac=True`` additionally
     quantizes activations per-row (the DAC model) and runs the fully-int8
-    kernel.  Runs on CPU via ``interpret=True``.  Equivalence is
-    tolerance-based: with a crossbar matrix that is already
+    kernel: compiled for the TPU, interpreted on the CPU backend (the mode
+    follows the backend; see ``kernels.mxv.resolve_interpret``).
+    Equivalence is tolerance-based: with a crossbar matrix that is already
     dequantized-int8 (``compile_model(..., quantizer=dequantize_int8)``)
-    the float path matches the numpy plane within ``atol=2e-5`` (matmul
-    rounding only); otherwise int8 weight-quantization error dominates.
+    the float path matches the numpy plane within ``atol=2e-5`` (f32
+    accumulation rounding only); otherwise int8 weight-quantization error
+    dominates.
 
 ``reference``
     The per-iteration loop over ``mxv_fn`` — the PR 1 execution structure,
@@ -301,13 +303,15 @@ class PallasPlane(ComputePlane):
     Weights come pre-quantized from the descriptor (int8 + per-row scale);
     batch sizes are bucketed to powers of two inside the padded kernel
     wrappers so streaming batches reuse a bounded set of compiled kernels.
-    ``interpret=True`` (default) runs the Pallas kernel on CPU.
+    ``interpret=None`` (default) interprets on the CPU backend and compiles
+    elsewhere; ``interpret=True`` off the CPU raises.
     """
 
     name = "pallas"
 
-    def __init__(self, interpret: bool = True, dac: bool = False):
-        self.interpret = interpret
+    def __init__(self, interpret: Optional[bool] = None, dac: bool = False):
+        from ..kernels.mxv import resolve_interpret
+        self.interpret = resolve_interpret(interpret)
         self.dac = dac
 
     def mxv_batch(self, desc, V):

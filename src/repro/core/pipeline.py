@@ -195,8 +195,6 @@ def pipeline_apply(stage_fns: List[Callable], params_stacked,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.compat import shard_map
-
     n_stages, n_ticks = schedule.table.shape
     n_items = xs.shape[0]
     assert len(stage_fns) == n_stages
@@ -235,9 +233,9 @@ def pipeline_apply(stage_fns: List[Callable], params_stacked,
         return outs
 
     pspec = jax.tree.map(lambda _: P(axis), params_stacked)
-    out = shard_map(body, mesh=mesh,
-                    in_specs=(pspec, P()), out_specs=P(),
-                    check=False)(params_stacked, xs)
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=(pspec, P()), out_specs=P(),
+                        check_vma=False)(params_stacked, xs)
     return out
 
 

@@ -42,7 +42,7 @@ argument —
     plane.
   * ``"pallas"``: the ``kernels/mxv.py`` crossbar kernel (int8 weight
     conductances + per-row scales; optional ``dac=True`` fully-int8 path),
-    running on CPU via ``interpret=True``.  Tolerance-based equivalence
+    compiled on a TPU, interpreted on the CPU.  Tolerance-based equivalence
     (``atol≈2e-5`` vs the float planes once the crossbar matrix is
     dequantized-int8, e.g. ``compile_model(..., quantizer=dequantize_int8)``).
   * ``"reference"``: the per-iteration loop over ``mxv_fn`` — the PR 1

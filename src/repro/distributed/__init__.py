@@ -1,4 +1,3 @@
-from .compat import HAS_NATIVE_SHARD_MAP, shard_map
 from .compression import (CompressionSpec, quantize_blockwise,
                           dequantize_blockwise, topk_sparsify,
                           topk_densify, init_error_feedback,
@@ -17,5 +16,4 @@ __all__ = [
     "ring_all_reduce", "ring_all_reduce_sharded", "make_accum_train_step",
     "plan_mesh", "rescale_tree", "make_mesh_from_plan", "degrade_sequence",
     "ElasticPlan",
-    "shard_map", "HAS_NATIVE_SHARD_MAP",
 ]

@@ -163,11 +163,8 @@ def hierarchical_psum_sharded(mesh, x: jax.Array, *, fast_axis: str = "data",
 
     ``x`` is the global array with the combined device axes leading (one
     slice per (slow, fast) device); every device returns the reduced value.
-    Uses the version-tolerant :mod:`repro.distributed.compat` shim.
     """
     from jax.sharding import PartitionSpec as P
-
-    from .compat import shard_map
 
     axes = (slow_axis, fast_axis) if slow_axis else (fast_axis,)
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
@@ -183,5 +180,6 @@ def hierarchical_psum_sharded(mesh, x: jax.Array, *, fast_axis: str = "data",
         return hierarchical_psum(xl[0], fast_axis=fast_axis,
                                  slow_axis=slow_axis, spec=spec)[None]
 
-    return shard_map(body, mesh, in_specs=P(axes), out_specs=P(axes),
-                     manual_axes=set(axes))(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(axes),
+                         out_specs=P(axes), axis_names=set(axes),
+                         check_vma=False)(x)

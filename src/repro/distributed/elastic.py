@@ -107,8 +107,8 @@ def plan_mesh(n_devices: int, cfg: ArchConfig, *,
 
 
 def make_mesh_from_plan(plan: ElasticPlan):
-    import jax
-    return jax.make_mesh(plan.mesh_shape, plan.axis_names)
+    from repro.launch.mesh import make_mesh
+    return make_mesh(plan.mesh_shape, plan.axis_names)
 
 
 def rescale_tree(host_tree: Any, spec_tree: Any, mesh) -> Any:

@@ -27,7 +27,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .compat import shard_map
 from .compression import CompressionSpec, compress_with_feedback
 
 
@@ -100,8 +99,7 @@ def ring_all_reduce_sharded(mesh, x: jax.Array, axis: str, *,
 
     ``x`` is the global array with the device axis leading (one slice per
     device of ``axis``); every device returns the full ring sum, so the
-    result has the same shape as ``x``.  Uses the version-tolerant
-    :mod:`repro.distributed.compat` shim; other mesh axes stay auto.
+    result has the same shape as ``x``.  Other mesh axes stay auto.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -114,8 +112,9 @@ def ring_all_reduce_sharded(mesh, x: jax.Array, axis: str, *,
     def body(xl):
         return ring_all_reduce(xl[0], axis, n_chunks=n_chunks)[None]
 
-    return shard_map(body, mesh, in_specs=P(axis), out_specs=P(axis),
-                     manual_axes={axis})(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(axis),
+                         out_specs=P(axis), axis_names={axis},
+                         check_vma=False)(x)
 
 
 # ------------------------------------------------- microbatch accum overlap

@@ -1,21 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# --- everything below may import jax (device count is now locked at 512) ---
-import argparse          # noqa: E402
-import json              # noqa: E402
-import time              # noqa: E402
-import traceback         # noqa: E402
-
-import jax               # noqa: E402
-
-from repro.configs.base import SHAPES, get_arch, shapes_for  # noqa: E402
-from repro.configs import archs  # noqa: E402,F401
-from repro.launch.mesh import make_production_mesh  # noqa: E402
-from repro.launch.roofline import (analytic_bytes, cost_dict,  # noqa: E402
-                                   parse_collectives, roofline_terms)
-from repro.launch.specs import make_cell, model_flops  # noqa: E402
-
 """Multi-pod dry-run (deliverable e).
 
 For every (architecture x input shape) cell and both production meshes
@@ -24,7 +6,24 @@ function with full-size ShapeDtypeStruct inputs + NamedShardings, print
 memory/cost analysis, and persist roofline terms to JSON.
 
 No arrays are ever allocated: params/optimizer/caches/batches are all SDS.
+The 512 devices are host (CPU) devices: ``main()`` sets XLA_FLAGS before
+JAX starts a backend; callers that import ``run_cell`` set it themselves.
 """
+
+import argparse
+import json
+import os
+import time
+import traceback
+
+import jax
+
+from repro.configs.base import SHAPES, get_arch, shapes_for
+from repro.configs import archs  # noqa: F401
+from repro.launch.mesh import make_production_mesh
+from repro.launch.roofline import (analytic_bytes, parse_collectives,
+                                   roofline_terms)
+from repro.launch.specs import make_cell, model_flops
 
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool,
@@ -41,7 +40,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         "multi_pod": multi_pod, "tag": tag, "ok": False,
     }
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                              donate_argnums=cell.donate)
             lowered = jitted.lower(*cell.args)
@@ -56,7 +55,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             print(f"[{arch}/{shape_name}] memory_analysis:", rec["memory"])
         except Exception as e:                           # CPU backend limits
             rec["memory"] = {"error": str(e)}
-        cost = cost_dict(compiled)
+        cost = compiled.cost_analysis()
         flops = float(cost.get("flops", 0.0))
         nbytes = float(cost.get("bytes accessed", 0.0))
         rec["cost"] = {"flops": flops, "bytes_accessed": nbytes}
@@ -105,11 +104,11 @@ def _lower_stats(arch: str, shape_name: str, multi_pod: bool, depth: int,
     if extra_overrides:
         ov.update(extra_overrides)
     cell = make_cell(arch, shape_name, mesh, overrides=ov)
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                          donate_argnums=cell.donate)
         compiled = jitted.lower(*cell.args).compile()
-    cost = cost_dict(compiled)
+    cost = compiled.cost_analysis()
     colls = parse_collectives(compiled.as_text())
     mem = {}
     try:
@@ -195,6 +194,9 @@ def run_cell_scaled(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -11,25 +11,21 @@ experiments/hillclimb/<arch>_<shape>_<variant>.json so EXPERIMENTS.md §Perf
 can diff before/after.
 """
 
+import argparse
+import json
 import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=512")
+import re
+import time
+from collections import defaultdict
 
-import argparse          # noqa: E402
-import json              # noqa: E402
-import re                # noqa: E402
-import time              # noqa: E402
-from collections import defaultdict  # noqa: E402
+import jax
 
-import jax               # noqa: E402
-
-from repro.configs.base import SHAPES, get_arch  # noqa: E402
-from repro.configs import archs  # noqa: E402,F401
-from repro.launch.mesh import make_production_mesh  # noqa: E402
-from repro.launch.roofline import (_DTYPE_BYTES,  # noqa: E402
-                                   analytic_bytes, cost_dict,
+from repro.configs.base import SHAPES, get_arch
+from repro.configs import archs  # noqa: F401
+from repro.launch.mesh import make_production_mesh
+from repro.launch.roofline import (_DTYPE_BYTES, analytic_bytes,
                                    parse_collectives, roofline_terms)
-from repro.launch.specs import make_cell, model_flops  # noqa: E402
+from repro.launch.specs import make_cell, model_flops
 
 _COLL_RE = re.compile(
     r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
@@ -95,11 +91,11 @@ def run(arch: str, shape_name: str, variant: str, depth: int,
     ov.update(extra)
     t0 = time.time()
     cell = make_cell(arch, shape_name, mesh, overrides=ov)
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                          donate_argnums=cell.donate)
         compiled = jitted.lower(*cell.args).compile()
-    cost = cost_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     colls = parse_collectives(hlo)
     flops = float(cost.get("flops", 0.0))
@@ -142,6 +138,11 @@ def run(arch: str, shape_name: str, variant: str, depth: int,
 
 
 def main():
+    # a compile-only tool: the production mesh as host devices
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=512")
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", required=True)

@@ -25,27 +25,22 @@ Run: PYTHONPATH=src python -m repro.launch.pipeline_prefill \
         --arch qwen2-7b --micro 4 [--seq-len 32768] [--batch 32]
 """
 
+import argparse
+import dataclasses
+import json
 import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=512")
+import time
+from typing import Any, Dict
 
-import argparse          # noqa: E402
-import dataclasses      # noqa: E402
-import json              # noqa: E402
-import time              # noqa: E402
-from typing import Any, Dict  # noqa: E402
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-import jax               # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
-
-from repro import sharding as sh  # noqa: E402
-from repro.configs.base import ArchConfig, get_arch  # noqa: E402
-from repro.configs import archs  # noqa: E402,F401
-from repro.core import pipeline  # noqa: E402
-from repro.distributed.compat import (HAS_NATIVE_SHARD_MAP,  # noqa: E402
-                                      shard_map)
-from repro.models import lm  # noqa: E402
+from repro import sharding as sh
+from repro.configs.base import ArchConfig, get_arch
+from repro.configs import archs  # noqa: F401
+from repro.core import pipeline
+from repro.models import lm
 
 
 def stage_config(cfg: ArchConfig, n_stages: int) -> ArchConfig:
@@ -80,39 +75,27 @@ def make_pipelined_prefill(cfg: ArchConfig, mesh: Mesh, n_micro: int,
     -> last-token hidden (n_micro, b_m, d)."""
     n_stages = mesh.shape["pod"]
     scfg = stage_config(cfg, n_stages)
-    if not HAS_NATIVE_SHARD_MAP:
-        # Old-JAX partial-auto shard_map: XLA's SPMD partitioner cannot
-        # handle the period scan (a while op) inside the manual region
-        # ("Check failed: IsManualSubgroup"); unroll the stage stack there.
-        scfg = dataclasses.replace(scfg, static_unroll=True)
     b_m = batch // n_micro
     # the paper's dependency automaton -> static schedule
     sched = pipeline.derive_schedule(["pointwise"] * (n_stages - 1), n_micro)
     table = jnp.asarray(sched.table)                 # (S, T)
     n_ticks = sched.n_ticks
 
-    def body(stage_params_local, embed_local, tokens_all, sid_arr):
+    def body(stage_params_local, embed_local, tokens_all):
         pme = jax.tree.map(lambda l: l[0], stage_params_local)
-        # stage id from the P("pod")-sharded arange input: lax.axis_index
-        # lowers to a PartitionId instruction, which XLA's SPMD partitioner
-        # rejects inside a partially-manual (auto data/model) shard_map
-        sid = sid_arr[0]
+        sid = jax.lax.axis_index("pod")
         pos = jnp.broadcast_to(jnp.arange(seq_len)[None], (b_m, seq_len))
         buf = jnp.zeros((b_m, seq_len, cfg.d_model),
                         jnp.dtype(cfg.compute_dtype))
         outs = jnp.zeros((n_micro, b_m, cfg.d_model),
                          jnp.dtype(cfg.compute_dtype))
-
+        # pod is manual here, data/model stay auto: pin the activation's
+        # batch dim to data so the partitioner never replicates it there
         act_spec = (P("data", None, None)
                     if b_m % mesh.shape["data"] == 0 else P(None, None, None))
-        # Python loop, not lax.scan: a collective-permute inside a scan under
-        # a partially-manual (auto data/model) shard_map trips XLA's SPMD
-        # partitioner on older JAX ("Check failed: IsManualSubgroup"); the
-        # tick count is static and small (n_micro + n_stages - 1), so the
-        # unroll costs little.  The constraint after each ppermute is the
-        # explicit sharding touchpoint the partitioner needs on collective
-        # outputs in this mode (value-neutral).
-        for tck in range(n_ticks):
+
+        def tick(carry, tck):
+            buf, outs = carry
             item = table[sid, tck]                   # -1 => idle
             safe = jnp.clip(item, 0, n_micro - 1)
             toks = jax.lax.dynamic_index_in_dim(
@@ -128,6 +111,9 @@ def make_pipelined_prefill(cfg: ArchConfig, mesh: Mesh, n_micro: int,
                 y, "pod",
                 [(i, (i + 1) % n_stages) for i in range(n_stages)])
             buf = jax.lax.with_sharding_constraint(buf, act_spec)
+            return (buf, outs), None
+
+        (_, outs), _ = jax.lax.scan(tick, (buf, outs), jnp.arange(n_ticks))
         # broadcast the final answer to all stages; f32 sidesteps an XLA-CPU
         # AllReducePromotion crash on bf16 all-reduce (copy-opcode clone bug)
         outs = jax.lax.psum(outs.astype(jnp.float32), "pod")
@@ -149,18 +135,14 @@ def make_pipelined_prefill(cfg: ArchConfig, mesh: Mesh, n_micro: int,
     tokens_spec = P(None, "data", None)
 
     def fn(stage_params, embed, tokens):
-        stage_ids = jax.lax.with_sharding_constraint(
-            jnp.arange(n_stages, dtype=jnp.int32),
-            NamedSharding(mesh, P("pod")))
-        h = shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P("pod"), stage_specs,
                                    is_leaf=lambda x: isinstance(x, P)),
-                      P(None), P(None), P("pod")),
+                      P(None), P(None)),
             out_specs=P(None),
-            manual_axes={"pod"},             # manual over pod; data/model auto
-            check=False)(stage_params, embed, tokens, stage_ids)
-        return h
+            axis_names={"pod"},              # manual over pod; data/model auto
+            check_vma=False)(stage_params, embed, tokens)
 
     embed_sds = jax.ShapeDtypeStruct(
         (1, cfg.vocab_size, cfg.d_model), jnp.dtype(cfg.param_dtype))
@@ -171,9 +153,35 @@ def make_pipelined_prefill(cfg: ArchConfig, mesh: Mesh, n_micro: int,
     return fn, (stage_sds, embed_sds, tokens_sds), in_sh, sched
 
 
+def pipeline_param_init(cfg: ArchConfig, n_stages: int, shardings):
+    """A jitted ``key -> (stage_params, embed)`` that draws the parameters
+    directly into ``shardings`` (the first two entries of
+    ``make_pipelined_prefill``'s in_shardings): each device materialises
+    only its own stage, so a model no single device can hold is never
+    gathered onto one.  Stage ``s`` is ``init_lm(stage_config(cfg,
+    n_stages), split(key)[s])["positions"]``."""
+    scfg = stage_config(cfg, n_stages)
+
+    def init(key):
+        ks = jax.random.split(key, n_stages + 1)
+        stages = jax.vmap(lambda k: lm.init_lm(scfg, k)["positions"])(
+            ks[:n_stages])
+        embed = (jax.random.normal(ks[-1], (cfg.vocab_size, cfg.d_model))
+                 * 0.02).astype(jnp.dtype(cfg.param_dtype))
+        return stages, embed[None]
+
+    return jax.jit(init, out_shardings=tuple(shardings[:2]))
+
+
 def main():
+    # a compile-only tool: the production multi-pod mesh as host devices
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=512")
+    from repro.launch.cache import enable_compile_cache
     from repro.launch.mesh import make_production_mesh
-    from repro.launch.roofline import cost_dict, parse_collectives
+    from repro.launch.roofline import parse_collectives
+
+    enable_compile_cache()
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-7b")
@@ -200,9 +208,9 @@ def main():
     t0 = time.time()
     fn, sds, in_sh, sched = make_pipelined_prefill(
         cfg, mesh, args.micro, args.seq_len, args.batch)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=in_sh).lower(*sds).compile()
-    cost = cost_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     colls = parse_collectives(hlo)
     mem = {}

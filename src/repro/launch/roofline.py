@@ -31,19 +31,6 @@ _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
 _SHAPE_RE = re.compile(r"\b([a-z]+\d*)\[([\d,]*)\]")
 
 
-def cost_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized across JAX versions.
-
-    Older jaxlibs return a single-element list of per-program dicts; newer
-    ones return the dict directly.  Every cost_analysis consumer in
-    ``launch/`` must read through this helper.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _shape_bytes(dtype: str, dims: str) -> int:
     nb = _DTYPE_BYTES.get(dtype)
     if nb is None:

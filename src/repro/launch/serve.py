@@ -11,6 +11,7 @@ import argparse
 
 from repro.configs import archs  # noqa: F401
 from repro.configs.base import get_arch, smoke_config
+from repro.launch.cache import enable_compile_cache
 from repro.serve import ServeEngine
 
 
@@ -23,6 +24,7 @@ def main() -> None:
     ap.add_argument("--reduced", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.reduced else get_arch(args.arch)
     eng = ServeEngine(cfg, max_len=args.prompt_len + args.gen_tokens + 1)
     stats = eng.throughput_probe(args.batch, args.prompt_len,

@@ -12,6 +12,7 @@ import time
 
 from repro.configs import archs  # noqa: F401  (register)
 from repro.configs.base import get_arch, smoke_config
+from repro.launch.cache import enable_compile_cache
 from repro.train import Trainer
 
 
@@ -30,6 +31,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.reduced else get_arch(args.arch)
     tr = Trainer(cfg=cfg, batch=args.batch, seq_len=args.seq_len,
                  ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,

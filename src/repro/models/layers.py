@@ -130,22 +130,10 @@ def positional_rotate(cfg: ArchConfig, x, pos):
 
 # --------------------------------------------------- sharding constraints
 def _ambient_mesh():
-    """The trace-time mesh: abstract mesh (jax.set_mesh) if populated, else
-    the physical mesh of a ``with mesh:`` context, else None (CPU tests)."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and am.axis_names:
-            return am
-    except Exception:
-        pass
-    try:
-        from jax.interpreters import pxla
-        pm = pxla.thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return pm
-    except Exception:
-        pass
-    return None
+    """The trace-time mesh set by ``jax.set_mesh``, or None when no mesh is
+    active (single-device runs)."""
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else am
 
 
 def _ambient_batch_axes() -> Optional[Tuple[str, ...]]:
@@ -172,23 +160,18 @@ def _strip_manual_axes(entry, manual):
 def _constrain(x, *spec):
     """with_sharding_constraint against the ambient mesh (no-op without).
 
-    Inside a shard_map body (entered through the repro.distributed.compat
-    shim), the body's manual axes are stripped from the spec — a constraint
-    naming a manual axis is illegal there, and the axis is already fixed by
-    the shard_map specs anyway.
+    Inside a shard_map body the body's manual axes are stripped from the
+    spec — a constraint naming a manual axis is illegal there, and the axis
+    is already fixed by the shard_map specs anyway.
     """
     from jax.sharding import PartitionSpec as P
-    try:
-        from repro.distributed.compat import current_manual_axes
-        manual = current_manual_axes()
-    except Exception:
-        manual = frozenset()
+    mesh = _ambient_mesh()
+    if mesh is None:
+        return x
+    manual = frozenset(mesh.manual_axes)
     if manual:
         spec = tuple(_strip_manual_axes(s, manual) for s in spec)
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def _attn_constraints(cfg: ArchConfig, q, k, v):

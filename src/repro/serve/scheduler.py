@@ -63,8 +63,10 @@ class ContinuousBatcher:
         self.max_len = max_len
         self.eos = eos
         self.model = build_model(cfg)
+        # jitted: the f32 draws behind bf16 weights stay inside one program
+        # instead of each living as its own device buffer
         self.params = params if params is not None else \
-            self.model.init(jax.random.key(seed))
+            jax.jit(self.model.init)(jax.random.key(seed))
         self.cache = self.model.init_cache(n_slots, max_len)
         self.slots: List[Optional[Request]] = [None] * n_slots
         self.queue: List[Request] = []

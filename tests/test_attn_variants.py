@@ -19,7 +19,8 @@ from repro.configs.base import smoke_config
 from repro.models import build_model
 from repro import sharding as sh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 
 def run_arch(arch, extra=None):
     base = smoke_config(arch)
@@ -45,7 +46,7 @@ def run_arch(arch, extra=None):
         params = jax.tree.map(
             lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
             params, pspecs, is_leaf=lambda x: hasattr(x, "shape"))
-        with mesh:
+        with jax.set_mesh(mesh):
             loss, metrics = jax.jit(model.loss)(params, batch)
         outs[name] = float(loss)
     ref = outs["default"]

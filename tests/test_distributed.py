@@ -151,16 +151,17 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, numpy as np
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.distributed import ring_all_reduce
 
-mesh = jax.make_mesh((4,), ("d",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("d",))
 x = np.arange(4 * 37, dtype=np.float32).reshape(4, 37) * 0.25
 
 for n_chunks in (1, 3):
     def body(xl):
         return ring_all_reduce(xl[0], "d", n_chunks=n_chunks)[None]
-    got = shard_map(body, mesh=mesh, in_specs=P("d"), out_specs=P("d"))(x)
+    got = jax.shard_map(body, mesh=mesh, in_specs=P("d"),
+                        out_specs=P("d"))(x)
     want = x.sum(0)
     for row in np.asarray(got):
         np.testing.assert_allclose(row, want, rtol=1e-6)
@@ -183,18 +184,18 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, numpy as np
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.distributed import CompressionSpec, hierarchical_psum
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("pod", "data"))
 x = np.random.default_rng(0).standard_normal((8, 64)).astype(np.float32)
 
 spec = CompressionSpec(kind="int8", block=32)
 def body(xl):
     return hierarchical_psum(xl[0], fast_axis="data", slow_axis="pod",
                              spec=spec)[None]
-got = shard_map(body, mesh=mesh, in_specs=P(("pod", "data")),
-                out_specs=P(("pod", "data")))(x.reshape(8, 1, 64)[:, 0, :])
+got = jax.shard_map(body, mesh=mesh, in_specs=P(("pod", "data")),
+                    out_specs=P(("pod", "data")))(x.reshape(8, 1, 64)[:, 0, :])
 want = x.sum(0)
 # int8 on the pod hop only: error bounded by quantization of 2 pod payloads
 err = np.abs(np.asarray(got)[0] - want)

@@ -7,6 +7,7 @@ target).  Tolerances are f32-accumulation tolerances.
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 pytest.importorskip("hypothesis")  # gated: optional test dep
@@ -208,3 +209,16 @@ def test_flash_decode_int8_matches_fp_within_quant_noise():
                           jnp.asarray(v), 256)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=0.05, atol=0.05)
+
+
+def test_interpret_mode_follows_the_backend(monkeypatch):
+    """None interprets only on the CPU; an explicit True off the CPU is
+    refused rather than run in the interpreter."""
+    from repro.kernels.mxv import resolve_interpret
+
+    assert resolve_interpret(None) is True          # this suite runs on CPU
+    assert resolve_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    with pytest.raises(ValueError):
+        resolve_interpret(True)

@@ -79,7 +79,8 @@ _EXEC_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.core import pipeline
 
-    mesh = jax.make_mesh((4,), ("stage",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("stage",))
     n_stages, n_items, dim = 4, 6, 16
     rng = np.random.default_rng(0)
     W = jnp.asarray(rng.normal(size=(n_stages, dim, dim)) / np.sqrt(dim),

@@ -1,12 +1,16 @@
 """Pipelined prefill (launch/pipeline_prefill.py): executing the 2-stage
 pod pipeline produces the same last-token hidden states as a sequential
-full-stack forward (subprocess, 4 host devices, (2 pod, 1 data, 2 model))."""
+full-stack forward (subprocess, 4 host devices, (2 pod, 1 data, 2 model)
+and (2 pod, 2 data, 1 model), the latter with the activation constraint on a
+data axis that really splits the batch)."""
 
 from __future__ import annotations
 
 import os
 import subprocess
 import sys
+
+import pytest
 
 _SCRIPT = r"""
 import os
@@ -23,7 +27,8 @@ from repro.launch.pipeline_prefill import (make_pipelined_prefill,
 
 cfg = smoke_config("llama3.2-3b")
 cfg = dataclasses.replace(cfg, n_layers=4, q_chunk=8)
-mesh = jax.make_mesh((2, 1, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh({shape}, ("pod", "data", "model"))
 
 seq_len, batch, n_micro = 16, 4, 2
 b_m = batch // n_micro
@@ -41,7 +46,7 @@ embed = params["embed"][None]
 
 fn, sds, in_sh, sched = make_pipelined_prefill(cfg, mesh, n_micro,
                                                seq_len, batch)
-with mesh:
+with jax.set_mesh(mesh):
     got = jax.jit(fn, in_shardings=in_sh)(stage_params, embed,
                                           jnp.asarray(tokens))
 
@@ -62,12 +67,14 @@ print("PIPELINE_PREFILL_OK", sched.utilization())
 """
 
 
-def test_pipelined_prefill_matches_sequential():
+@pytest.mark.parametrize("shape", [(2, 1, 2), (2, 2, 1)])
+def test_pipelined_prefill_matches_sequential(shape):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
+    script = _SCRIPT.replace("{shape}", repr(shape))
+    r = subprocess.run([sys.executable, "-c", script], env=env,
                        capture_output=True, text=True, timeout=900)
     assert "PIPELINE_PREFILL_OK" in r.stdout, \
         r.stdout[-2000:] + r.stderr[-3000:]

@@ -7,7 +7,7 @@ from __future__ import annotations
 import jax
 import numpy as np
 import pytest
-from jax.sharding import AbstractMesh, PartitionSpec as P
+from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
 
 from repro import sharding as sh
 from repro.configs import archs
@@ -15,8 +15,10 @@ from repro.configs.base import get_arch, SHAPES, shapes_for
 from repro.models import build_model
 
 MESHES = {
-    "single": AbstractMesh((("data", 16), ("model", 16))),
-    "multi": AbstractMesh((("pod", 2), ("data", 16), ("model", 16))),
+    "single": AbstractMesh((16, 16), ("data", "model"),
+                           axis_types=(AxisType.Auto,) * 2),
+    "multi": AbstractMesh((2, 16, 16), ("pod", "data", "model"),
+                          axis_types=(AxisType.Auto,) * 3),
 }
 
 

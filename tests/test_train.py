@@ -116,7 +116,8 @@ def test_elastic_reshard_on_load(tmp_path):
 
         cfg = smoke_config("llama3.2-3b")
         model = build_model(cfg)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         params = jax.eval_shape(lambda: model.init(jax.random.key(0)))
         opt = jax.eval_shape(lambda p: adamw_init(p, cfg.adam_dtype), params)
         tmpl = TrainState(params, opt, jax.ShapeDtypeStruct((), jnp.int32))
