@@ -110,6 +110,11 @@ def _measure_cm_tenancy(smoke: bool):
 
 
 # ------------------------------------------------------------- JAX batcher
+def _slot_utilization(b: ContinuousBatcher) -> float:
+    st = b.stats
+    return st["slot_busy_ticks"] / max(1, st["steps"] * b.n_slots)
+
+
 def _measure(n_requests: int = 12, n_slots: int = 4, seed: int = 0):
     cfg = smoke_config("qwen2-7b")
     rng = np.random.default_rng(seed)
@@ -152,12 +157,12 @@ def _measure(n_requests: int = 12, n_slots: int = 4, seed: int = 0):
     rows = {
         "continuous": {
             "steps": continuous.stats["steps"],
-            "utilization": round(continuous.utilization, 3),
+            "utilization": round(_slot_utilization(continuous), 3),
             "prefills": continuous.stats["prefills"],
         },
         "static_waves": {
             "steps": static_steps,
-            "utilization": round(static.utilization, 3),
+            "utilization": round(_slot_utilization(static), 3),
             "prefills": static.stats["prefills"],
         },
     }

@@ -80,9 +80,10 @@ def jax_batcher():
     for r in reqs:
         print(f"request {r.rid}: prompt_len={len(r.prompt)} "
               f"-> {len(r.out)} tokens {r.out}")
-    print(f"engine steps: {engine.stats['steps']}, "
-          f"prefills: {engine.stats['prefills']}, "
-          f"slot utilization: {engine.utilization:.1%}")
+    st = engine.stats
+    print(f"engine steps: {st['steps']}, prefills: {st['prefills']}, "
+          f"slot utilization: "
+          f"{st['slot_busy_ticks'] / (st['steps'] * engine.n_slots):.1%}")
 
 
 def main():

@@ -13,19 +13,37 @@ where finished sequences free their slot for the next queued request
 
 Determinism invariant (tested): a request's output is identical whether it
 ran alone or was co-scheduled with arbitrary other traffic.
+
+The host loop marks its boundaries with ``jax.profiler.TraceAnnotation``
+spans named in ``SPANS``.  Without a profiler session they cost about a
+microsecond each; in a profiler trace they share the device's clock, so
+device time and idle gaps can be attributed to what the host was doing.
+The three programs have stable names: ``jit_decode_step``, ``jit_prefill``
+(one per bucket) and ``jit_insert_slot``.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.models import build_model
+
+# the batcher's spans; one request's spans share its ``rid``
+SPANS = ("batcher.submit",   # submit: rid
+         "batcher.admit",    # token upload, prefill dispatch, the wait for
+                             #   the previous insert, insert dispatch:
+                             #   rid, bucket, n (true prompt length)
+         "batcher.decode",   # token upload and decode dispatch: live, queued
+         "batcher.fetch",    # wait for the decode program, copy logits out
+         "batcher.sample")   # per-slot argmax, append, retire
 
 
 @dataclasses.dataclass
@@ -53,8 +71,34 @@ def _buckets(n: int, sizes=(16, 32, 64, 128, 256, 512, 1024, 2048, 4096)):
     return sizes[-1]
 
 
+@jax.jit
+def insert_slot(cache: Any, one_cache: Any, slot) -> Any:
+    """``cache`` with the single-sequence ``one_cache`` written into batch
+    slot ``slot`` (a traced int32: one program for every slot).  Out of
+    place: the cache is not donated."""
+    def ins(batch_leaf, one_leaf):
+        if batch_leaf.ndim == 1:                         # length (B,)
+            return batch_leaf.at[slot].set(one_leaf[0])
+        # (P, B, ...) vs (P, 1, ...)
+        return jax.lax.dynamic_update_slice_in_dim(
+            batch_leaf, one_leaf.astype(batch_leaf.dtype), slot, axis=1)
+    return jax.tree.map(ins, cache, one_cache)
+
+
 class ContinuousBatcher:
-    """Slot-based continuous batching over a fixed decode batch."""
+    """Slot-based continuous batching over a fixed decode batch.
+
+    ``stats`` is the operator's view when no profiler runs, counted on the
+    host since construction:
+
+    * ``steps``: decode steps; ``slot_busy_ticks``: live slots summed over
+      them (``slot_busy_ticks / (steps * n_slots)`` is slot utilization);
+    * ``queued_ticks``: requests still queued at each decode step, summed;
+    * ``prefills``: admitted requests; ``prefill_tokens`` their true prompt
+      tokens and ``prefill_padded_tokens`` the bucket tokens prefilled;
+    * ``queue_wait_s``: admission minus submission time on the host's
+      ``perf_counter``, summed over admitted requests.
+    """
 
     def __init__(self, cfg: ArchConfig, n_slots: int, max_len: int,
                  params: Any = None, eos: Optional[int] = None, seed: int = 0):
@@ -71,16 +115,20 @@ class ContinuousBatcher:
         self.slots: List[Optional[Request]] = [None] * n_slots
         self.queue: List[Request] = []
         self.last_tok = np.zeros((n_slots,), np.int32)
-        self.stats = {"steps": 0, "prefills": 0, "slot_busy_ticks": 0}
+        self.stats = {"steps": 0, "prefills": 0, "slot_busy_ticks": 0,
+                      "queued_ticks": 0, "prefill_tokens": 0,
+                      "prefill_padded_tokens": 0, "queue_wait_s": 0.0}
+        self._submitted: Dict[int, float] = {}          # id(req) -> time
 
-        self._decode = jax.jit(
-            lambda p, c, t: self.model.decode_step(p, c, t))
+        def decode_step(p, c, t):
+            return self.model.decode_step(p, c, t)
+        self._decode = jax.jit(decode_step)
         self._prefill_cache: Dict[int, Any] = {}        # bucket -> jit fn
 
     # ------------------------------------------------------------ plumbing
     def _prefill_fn(self, bucket: int):
         if bucket not in self._prefill_cache:
-            def fn(p, tokens, true_len):
+            def prefill(p, tokens, true_len):
                 # tokens (1, bucket); run full-bucket prefill, then reset
                 # length to the true prompt length (suffix is padding that
                 # the length mask hides from future attention)
@@ -89,40 +137,44 @@ class ContinuousBatcher:
                 cache["length"] = jnp.full((1,), true_len, jnp.int32)
                 # logits at the true last token, not the padded tail
                 return cache
-            self._prefill_cache[bucket] = jax.jit(fn)
+            self._prefill_cache[bucket] = jax.jit(prefill)
         return self._prefill_cache[bucket]
-
-    def _insert_slot(self, slot: int, one_cache: Any) -> None:
-        """Write a single-sequence cache into batch slot ``slot``."""
-        def ins(batch_leaf, one_leaf):
-            if batch_leaf.ndim == 1:                     # length (B,)
-                return batch_leaf.at[slot].set(one_leaf[0])
-            # (P, B, ...) vs (P, 1, ...)
-            return jax.lax.dynamic_update_slice_in_dim(
-                batch_leaf, one_leaf.astype(batch_leaf.dtype), slot, axis=1)
-        self.cache = jax.tree.map(ins, self.cache, one_cache)
 
     def _slot_logits_token(self, logits_row: np.ndarray) -> int:
         return int(np.argmax(logits_row))
 
     # ------------------------------------------------------------- control
     def submit(self, req: Request) -> None:
-        self.queue.append(req)
+        with TraceAnnotation("batcher.submit", rid=req.rid):
+            self._submitted[id(req)] = time.perf_counter()
+            self.queue.append(req)
 
     def _admit(self) -> None:
         for slot in range(self.n_slots):
             if self.slots[slot] is not None or not self.queue:
                 continue
+            t_admit = time.perf_counter()
             req = self.queue.pop(0)
             sp = len(req.prompt)
             bucket = _buckets(sp)
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :sp] = req.prompt
-            cache1 = self._prefill_fn(bucket)(
-                self.params, jnp.asarray(toks), sp)
-            self._insert_slot(slot, cache1)
+            with TraceAnnotation("batcher.admit", rid=req.rid, bucket=bucket,
+                                 n=sp):
+                toks = np.zeros((1, bucket), np.int32)
+                toks[0, :sp] = req.prompt
+                cache1 = self._prefill_fn(bucket)(
+                    self.params, jnp.asarray(toks), sp)
+                # an insert's new cache is allocated when it is dispatched,
+                # and the cache it replaces is freed only once the device
+                # has run it: wait for the previous insert, so that serial
+                # admissions hold two caches, not one each
+                jax.block_until_ready(self.cache)
+                self.cache = insert_slot(self.cache, cache1, np.int32(slot))
             self.slots[slot] = req
-            self.stats["prefills"] += 1
+            st = self.stats
+            st["prefills"] += 1
+            st["prefill_tokens"] += sp
+            st["prefill_padded_tokens"] += bucket
+            st["queue_wait_s"] += t_admit - self._submitted.pop(id(req))
             # next-token seed: greedy over the last *true* prompt position.
             # Re-run one decode ahead of the loop would double-step; instead
             # take argmax of the prefill logits recomputed at true length:
@@ -135,20 +187,26 @@ class ContinuousBatcher:
         live = [i for i, r in enumerate(self.slots) if r is not None]
         if not live:
             return
-        self.stats["steps"] += 1
-        self.stats["slot_busy_ticks"] += len(live)
-        logits, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(self.last_tok))
-        logits = np.asarray(logits)
-        for i in live:
-            req = self.slots[i]
-            tok = self._slot_logits_token(logits[i])
-            req.out.append(tok)
-            self.last_tok[i] = tok
-            if (self.eos is not None and tok == self.eos) or \
-                    len(req.out) >= req.max_new:
-                req.done = True
-                self.slots[i] = None                     # free the slot
+        st = self.stats
+        st["steps"] += 1
+        st["slot_busy_ticks"] += len(live)
+        st["queued_ticks"] += len(self.queue)
+        with TraceAnnotation("batcher.decode", live=len(live),
+                             queued=len(self.queue)):
+            logits, self.cache = self._decode(
+                self.params, self.cache, jnp.asarray(self.last_tok))
+        with TraceAnnotation("batcher.fetch"):
+            logits = np.asarray(logits)
+        with TraceAnnotation("batcher.sample"):
+            for i in live:
+                req = self.slots[i]
+                tok = self._slot_logits_token(logits[i])
+                req.out.append(tok)
+                self.last_tok[i] = tok
+                if (self.eos is not None and tok == self.eos) or \
+                        len(req.out) >= req.max_new:
+                    req.done = True
+                    self.slots[i] = None                 # free the slot
 
     def run_until_drained(self, max_steps: int = 10_000) -> None:
         for _ in range(max_steps):
@@ -156,8 +214,3 @@ class ContinuousBatcher:
                 return
             self.step()
         raise RuntimeError("scheduler did not drain")
-
-    @property
-    def utilization(self) -> float:
-        s = self.stats
-        return s["slot_busy_ticks"] / max(1, s["steps"] * self.n_slots)
