@@ -18,22 +18,9 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from . import layers as L
-from .lm import _stack, chunked_ce_loss
+from .lm import _stack, chunked_ce_loss, run_periods
 
 Params = Dict[str, Any]
-
-
-def _run_stack(cfg: ArchConfig, body, x, stacked, n_layers: int):
-    """scan over stacked layer params, or an unrolled loop (dry-run)."""
-    if cfg.static_unroll:
-        outs = []
-        for i in range(n_layers):
-            x, y = body(x, jax.tree.map(lambda l: l[i], stacked))
-            outs.append(y)
-        ys = (jax.tree.map(lambda *ls: jnp.stack(ls), *outs)
-              if outs and outs[0] is not None else None)
-        return x, ys
-    return jax.lax.scan(body, x, stacked)
 
 
 def init_encdec(cfg: ArchConfig, key) -> Params:
@@ -87,7 +74,7 @@ def encode(cfg: ArchConfig, params: Params, embeds):
             run = jax.checkpoint(run)
         return run(x), None
 
-    x, _ = _run_stack(cfg, body, x, params["encoder"], cfg.encoder_layers)
+    x, _ = run_periods(cfg, body, x, params["encoder"], cfg.encoder_layers)
     return L.apply_norm(cfg, params["enc_final_norm"], x)
 
 
@@ -110,7 +97,7 @@ def decode_train(cfg: ArchConfig, params: Params, tokens, enc_out):
             run = jax.checkpoint(run)
         return run(x), None
 
-    x, _ = _run_stack(cfg, body, x, params["decoder"], cfg.n_layers)
+    x, _ = run_periods(cfg, body, x, params["decoder"], cfg.n_layers)
     return L.apply_norm(cfg, params["final_norm"], x)
 
 
@@ -127,9 +114,11 @@ def init_encdec_cache(cfg: ArchConfig, batch: int, max_len: int,
                       enc_len: int) -> Dict:
     cdt = jnp.dtype(cfg.compute_dtype)
     n_dec = cfg.n_layers
-    kv = jnp.zeros((n_dec, batch, max_len, cfg.n_kv_heads, cfg.hd), cdt)
-    xkv = jnp.zeros((n_dec, batch, enc_len, cfg.n_kv_heads, cfg.hd), cdt)
-    return {"k": kv, "v": kv, "xk": xkv, "xv": xkv,
+    kv = (n_dec, batch, max_len, cfg.n_kv_heads, cfg.hd)
+    xkv = (n_dec, batch, enc_len, cfg.n_kv_heads, cfg.hd)
+    # a buffer of its own per leaf, so that the cache can be donated
+    return {"k": jnp.zeros(kv, cdt), "v": jnp.zeros(kv, cdt),
+            "xk": jnp.zeros(xkv, cdt), "xv": jnp.zeros(xkv, cdt),
             "length": jnp.zeros((batch,), jnp.int32)}
 
 
@@ -156,8 +145,8 @@ def encdec_prefill(cfg: ArchConfig, params: Params, embeds, tokens,
         x = x + L.mlp(cfg, p["mlp"], h)
         return x, (k, v, xk, xv)
 
-    x, (ks, vs, xks, xvs) = _run_stack(cfg, body, x, params["decoder"],
-                                       cfg.n_layers)
+    x, (ks, vs, xks, xvs) = run_periods(cfg, body, x, params["decoder"],
+                                        cfg.n_layers)
     h = L.apply_norm(cfg, params["final_norm"], x)
     cdt = jnp.dtype(cfg.compute_dtype)
     pad = [(0, 0), (0, 0), (0, max_len - s), (0, 0), (0, 0)]
@@ -171,26 +160,31 @@ def encdec_prefill(cfg: ArchConfig, params: Params, embeds, tokens,
 
 
 def encdec_decode_step(cfg: ArchConfig, params: Params, cache: Dict, tokens):
-    """One decoder token with self-cache + cross-cache.  tokens (B,)."""
+    """One decoder token with self-cache + cross-cache.  tokens (B,).
+
+    The self-attention K/V ride in the scan's carry and each layer writes
+    only its new rows, as in ``lm.decode_step``."""
     length = cache["length"]
     x = params["embed"][tokens][:, None]                # (B, 1, d)
 
-    def body(x, per):
-        p, ck, cv, cxk, cxv = per
+    def body(carry, per):
+        x, kv = carry
+        p, cxk, cxv, i = per
         h = L.apply_norm(cfg, p["norm1"], x)
-        y, nk, nv = L.attention_decode(cfg, p["attn"], h, ck, cv, length)
+        y, kv = L.attention_decode(cfg, p["attn"], h, kv, length, layer=i)
         x = x + y
         h = L.apply_norm(cfg, p["norm3"], x)
         x = x + L.cross_attention(cfg, p["cross"], h, (cxk, cxv))
         h = L.apply_norm(cfg, p["norm2"], x)
         x = x + L.mlp(cfg, p["mlp"], h)
-        return x, (nk, nv)
+        return (x, kv), None
 
-    x, (nks, nvs) = _run_stack(
-        cfg, body, x, (params["decoder"], cache["k"], cache["v"],
-                       cache["xk"], cache["xv"]), cfg.n_layers)
+    (x, kv), _ = run_periods(
+        cfg, body, (x, {"k": cache["k"], "v": cache["v"]}),
+        (params["decoder"], cache["xk"], cache["xv"],
+         jnp.arange(cfg.n_layers)), cfg.n_layers)
     h = L.apply_norm(cfg, params["final_norm"], x)[:, 0]
     w = params["embed"]
     logits = h.astype(jnp.float32) @ w.astype(jnp.float32).T
-    new_cache = dict(cache, k=nks, v=nvs, length=length + 1)
+    new_cache = dict(cache, **kv, length=length + 1)
     return logits, new_cache

@@ -463,13 +463,18 @@ def kv_dequantize(q, scale, dtype):
     return (q.astype(jnp.float32) * scale).astype(dtype)
 
 
-def attention_decode(cfg: ArchConfig, p: Params, x, cache_k, cache_v,
-                     length, k_scale=None, v_scale=None):
-    """One-token decode: x (B, 1, d); cache (B, S, Hkv, D); length (B,).
+def attention_decode(cfg: ArchConfig, p: Params, x, cache, length, layer):
+    """One-token decode: x (B, 1, d); length (B,).
 
-    Writes the new K/V at ``length`` and attends over positions < length+1.
-    Returns (y (B,1,d), new_k, new_v) — plus (new_k_scale, new_v_scale) when
-    the cache is int8-quantized (cfg.kv_dtype == "int8").
+    ``cache`` holds ``k``/``v`` (P, B, S, Hkv, D) stacked over periods (or
+    layers), plus ``k_scale``/``v_scale`` (P, B, S, Hkv, 1) when the cache
+    is int8-quantized (cfg.kv_dtype == "int8"); this call reads and writes
+    period ``layer``.
+
+    Writes each slot's new K/V row at ``length`` and nowhere else (a slot at
+    ``length >= S`` gets no write), then attends over positions <= length.
+    Returns (y (B,1,d), the written cache).  Only the new rows are written,
+    so a donated cache is updated in place.
     """
     b = x.shape[0]
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
@@ -479,39 +484,34 @@ def attention_decode(cfg: ArchConfig, p: Params, x, cache_k, cache_v,
     q = positional_rotate(cfg, q, pos)
     k = positional_rotate(cfg, k, pos)
 
-    oh = jax.nn.one_hot(length, cache_k.shape[1],
-                        dtype=jnp.float32)              # (B, S)
-    ohk = oh[..., None, None]
     if quant:
         k8, ks = kv_quantize(k)
         v8, vs = kv_quantize(v)
-        new_k = (cache_k.astype(jnp.float32) * (1 - ohk)
-                 + ohk * k8.astype(jnp.float32)).astype(jnp.int8)
-        new_v = (cache_v.astype(jnp.float32) * (1 - ohk)
-                 + ohk * v8.astype(jnp.float32)).astype(jnp.int8)
-        new_ks = k_scale * (1 - ohk) + ohk * ks
-        new_vs = v_scale * (1 - ohk) + ohk * vs
-        k_eff = new_k.astype(jnp.float32) * new_ks      # fused dequant
-        v_eff = new_v.astype(jnp.float32) * new_vs
+        rows = {"k": k8, "v": v8, "k_scale": ks, "v_scale": vs}
     else:
-        new_k = cache_k * (1 - ohk.astype(cache_k.dtype)) \
-            + ohk.astype(cache_k.dtype) * k
-        new_v = cache_v * (1 - ohk.astype(cache_v.dtype)) \
-            + ohk.astype(cache_v.dtype) * v
-        k_eff, v_eff = new_k, new_v
+        rows = {"k": k, "v": v}
+    # slots differ in length, so one scatter, not one dynamic_update_slice
+    # (which would clamp an idle slot's write onto position S-1)
+    cache = {n: c.at[layer, jnp.arange(b), length].set(
+                 rows[n][:, 0].astype(c.dtype), mode="drop")
+             for n, c in cache.items()}
+    c = {n: l[layer] for n, l in cache.items()}
+    if quant:
+        k_eff = c["k"].astype(jnp.float32) * c["k_scale"]   # fused dequant
+        v_eff = c["v"].astype(jnp.float32) * c["v_scale"]
+    else:
+        k_eff, v_eff = c["k"], c["v"]
 
     g = hq // hkv
     qg = q.reshape(b, hkv, g, hd)                       # squeeze Sq=1
     s = jnp.einsum("bhgd,bkhd->bhgk", qg.astype(jnp.float32),
                    k_eff.astype(jnp.float32)) / np.sqrt(hd)
-    mask = (jnp.arange(cache_k.shape[1])[None] <= length[:, None])
+    mask = (jnp.arange(k_eff.shape[1])[None] <= length[:, None])
     s = jnp.where(mask[:, None, None], s, -1e30)
     pr = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgk,bkhd->bhgd", pr, v_eff.astype(jnp.float32))
     y = o.reshape(b, 1, hq * hd).astype(x.dtype) @ p["wo"]
-    if quant:
-        return y, new_k, new_v, new_ks, new_vs
-    return y, new_k, new_v
+    return y, cache
 
 
 # ---------------------------------------------------------------------- MLPs

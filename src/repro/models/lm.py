@@ -8,7 +8,8 @@ positions — HLO size stays O(period), not O(n_layers), which keeps 94-layer
 configs compilable at 512 devices.
 
 Caches for decode mirror the same structure: per period-position, leaves
-stacked over periods.
+stacked over periods.  Decode carries the stacked K/V through its scan and
+writes one row per sequence, so a donated cache is updated in place.
 """
 
 from __future__ import annotations
@@ -191,6 +192,20 @@ def backbone(cfg: ArchConfig, params: Params, x, pos,
     return L.apply_norm(cfg, params["final_norm"], x), aux, stacked
 
 
+def run_periods(cfg: ArchConfig, body, carry, stacked, n: int):
+    """``lax.scan(body, carry, stacked)`` over ``n`` stacked periods or
+    layers, or an unrolled loop under ``cfg.static_unroll`` (dry-run)."""
+    if cfg.static_unroll:
+        outs = []
+        for i in range(n):
+            carry, y = body(carry, jax.tree.map(lambda l: l[i], stacked))
+            outs.append(y)
+        ys = (jax.tree.map(lambda *ls: jnp.stack(ls), *outs)
+              if outs and outs[0] is not None else None)
+        return carry, ys
+    return jax.lax.scan(body, carry, stacked)
+
+
 def run_stack(cfg: ArchConfig, positions, x, pos):
     """Apply the period stack only (no embed / final norm / head): the unit
     a pipeline *stage* executes (launch/pipeline_prefill.py).  Aux losses
@@ -283,14 +298,14 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> Dict:
     for spec in struct:
         if spec["mixer"] == "attn":
             kdt = jnp.int8 if cfg.kv_dtype == "int8" else cdt
-            kv = jnp.zeros((np_, batch, max_len, cfg.n_kv_heads, cfg.hd), kdt)
+            shape = (np_, batch, max_len, cfg.n_kv_heads, cfg.hd)
+            # a buffer of its own per leaf: a donated cache may not hand
+            # one buffer to two leaves
+            e = {"k": jnp.zeros(shape, kdt), "v": jnp.zeros(shape, kdt)}
             if cfg.kv_dtype == "int8":
-                sc = jnp.ones((np_, batch, max_len, cfg.n_kv_heads, 1),
-                              jnp.float32)
-                entries.append({"k": kv, "v": kv,
-                                "k_scale": sc, "v_scale": sc})
-            else:
-                entries.append({"k": kv, "v": kv})
+                e["k_scale"] = jnp.ones(shape[:-1] + (1,), jnp.float32)
+                e["v_scale"] = jnp.ones(shape[:-1] + (1,), jnp.float32)
+            entries.append(e)
         else:
             entries.append({
                 "conv": jnp.zeros((np_, batch, s.conv - 1, din), cdt),
@@ -303,14 +318,25 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> Dict:
 def decode_step(cfg: ArchConfig, params: Params, cache: Dict, tokens):
     """One token for every sequence.  tokens (B,) int32 (or (B, d) embeds).
 
-    Returns (logits (B, V), new_cache).
+    Returns (logits (B, V), new_cache).  Attention positions carry their
+    stacked K/V through the scan over periods and write only each slot's
+    new row, so a donated cache is updated in place; Mamba states are
+    small and pass through as scanned inputs and outputs.
     """
     struct = period_structure(cfg)
+    np_ = n_periods(cfg)
     length = cache["length"]
     if cfg.embed_inputs and tokens.ndim == 2:
         x = tokens[:, None].astype(jnp.dtype(cfg.compute_dtype))
     else:
         x = params["embed"][tokens][:, None]            # (B, 1, d)
+
+    def ffn(spec, p, x):
+        h = L.apply_norm(cfg, p["norm2"], x)
+        if spec["ffn"] == "moe":
+            y, _ = L.moe(cfg, p["moe"], h.swapaxes(0, 1))  # (1, B, d) group
+            return x + y.swapaxes(0, 1)
+        return x + L.mlp(cfg, p["mlp"], h)
 
     new_layers = []
     for pos_i, spec in enumerate(struct):
@@ -318,59 +344,25 @@ def decode_step(cfg: ArchConfig, params: Params, cache: Dict, tokens):
         c_stacked = cache["layers"][pos_i]
 
         if spec["mixer"] == "attn":
-            def body(x, per):                           # scan over periods
-                p, c = per
+            def body(carry, per, spec=spec):
+                x, c = carry
+                p, i = per
+                h = L.apply_norm(cfg, p["norm1"], x)
+                y, c = L.attention_decode(cfg, p["attn"], h, c, length,
+                                          layer=i)
+                return (ffn(spec, p, x + y), c), None
 
-                def blk(x):
-                    h = L.apply_norm(cfg, p["norm1"], x)
-                    if cfg.kv_dtype == "int8":
-                        y, nk, nv, nks, nvs = L.attention_decode(
-                            cfg, p["attn"], h, c["k"], c["v"], length,
-                            c["k_scale"], c["v_scale"])
-                        new_c = {"k": nk, "v": nv,
-                                 "k_scale": nks, "v_scale": nvs}
-                    else:
-                        y, nk, nv = L.attention_decode(
-                            cfg, p["attn"], h, c["k"], c["v"], length)
-                        new_c = {"k": nk, "v": nv}
-                    x = x + y
-                    h = L.apply_norm(cfg, p["norm2"], x)
-                    if spec["ffn"] == "moe":
-                        y2, _ = L.moe(cfg, p["moe"],
-                                      h.swapaxes(0, 1))  # (1, B, d) group
-                        y2 = y2.swapaxes(0, 1)
-                    else:
-                        y2 = L.mlp(cfg, p["mlp"], h)
-                    return x + y2, new_c
-                return blk(x)
+            (x, new_c), _ = run_periods(cfg, body, (x, c_stacked),
+                                        (p_stacked, jnp.arange(np_)), np_)
         else:
-            def body(x, per):
+            def body(x, per, spec=spec):
                 p, c = per
+                h = L.apply_norm(cfg, p["norm1"], x)
+                y, nconv, nssm = L.mamba_decode(
+                    cfg, p["mamba"], h, c["conv"], c["ssm"])
+                return ffn(spec, p, x + y), {"conv": nconv, "ssm": nssm}
 
-                def blk(x):
-                    h = L.apply_norm(cfg, p["norm1"], x)
-                    y, nconv, nssm = L.mamba_decode(
-                        cfg, p["mamba"], h, c["conv"], c["ssm"])
-                    x = x + y
-                    h = L.apply_norm(cfg, p["norm2"], x)
-                    if spec["ffn"] == "moe":
-                        y2, _ = L.moe(cfg, p["moe"], h.swapaxes(0, 1))
-                        y2 = y2.swapaxes(0, 1)
-                    else:
-                        y2 = L.mlp(cfg, p["mlp"], h)
-                    return x + y2, {"conv": nconv, "ssm": nssm}
-                return blk(x)
-
-        if cfg.static_unroll:
-            ys = []
-            np_ = n_periods(cfg)
-            for per in range(np_):
-                x, y = body(x, jax.tree.map(lambda l: l[per],
-                                            (p_stacked, c_stacked)))
-                ys.append(y)
-            new_c = jax.tree.map(lambda *ls: jnp.stack(ls), *ys)
-        else:
-            x, new_c = jax.lax.scan(body, x, (p_stacked, c_stacked))
+            x, new_c = run_periods(cfg, body, x, (p_stacked, c_stacked), np_)
         new_layers.append(new_c)
 
     h = L.apply_norm(cfg, params["final_norm"], x)[:, 0]   # (B, d)

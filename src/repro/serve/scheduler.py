@@ -7,7 +7,8 @@ where finished sequences free their slot for the next queued request
 
   * one jit'd single-sequence prefill per prompt-length *bucket* writes a
     new request's KV/SSM state directly into its slot of the live cache;
-  * one jit'd batched ``decode_step`` advances every live slot;
+  * one jit'd batched ``decode_step`` advances every live slot, updating
+    the donated cache in place;
   * per-slot lengths come from the cache's ``length`` vector, so ragged
     batches are exact (the model masks attention by length).
 
@@ -122,7 +123,9 @@ class ContinuousBatcher:
 
         def decode_step(p, c, t):
             return self.model.decode_step(p, c, t)
-        self._decode = jax.jit(decode_step)
+        # the cache is donated: the step writes each slot's new K/V row
+        # into it in place, and the cache it was given is gone after
+        self._decode = jax.jit(decode_step, donate_argnums=(1,))
         self._prefill_cache: Dict[int, Any] = {}        # bucket -> jit fn
 
     # ------------------------------------------------------------ plumbing

@@ -10,6 +10,7 @@ at import: only one process at a time may load the TPU library.
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -90,3 +91,55 @@ def test_qwen2_7b_decode_step_compiles_one_layer(one_chip):
         params, cache, _sds((8,), jnp.int32, one_chip)).compile()
     # weights + cache of one layer, embed and head: well inside 16 GB
     assert compiled.memory_analysis().argument_size_in_bytes < 4 * 2**30
+
+
+def _fusion_ops(hlo: str):
+    """(name, kind, output shape, opcodes of its fused computation) for
+    every fusion in an optimized HLO text."""
+    bodies = {m.group(1): m.group(2) for m in re.finditer(
+        r"\n(%[\w.\-]+) \(.*?\) -> .*? \{\n(.*?)\n\}", hlo, re.S)}
+    for m in re.finditer(r"(%[\w.\-]+) = (.*?) fusion\(.*?kind=(k\w+), "
+                         r"calls=(%[\w.\-]+)", hlo):
+        ops = {o.group(1) for line in bodies[m.group(4)].splitlines()
+               for o in [re.search(r"(?:^|\s)([a-z][a-z0-9\-]*)\(",
+                                   line.split(" = ", 1)[-1])] if o}
+        yield m.group(1), m.group(3), m.group(2), ops
+
+
+def test_qwen2_7b_decode_updates_donated_cache_in_place(one_chip):
+    """Two qwen2-7b layers at the serving cell's 32 slots x 4096 positions,
+    jitted with the cache donated as ``ContinuousBatcher`` does: the output
+    cache is the input's buffers, and no fusion rewrites K/V beyond the new
+    rows.  The per-layer read slice (``dynamic-slice``, and a layout copy
+    of it for the attention) is the one copy of the cache left."""
+    from repro.configs.base import depth_cut
+    from repro.models import build_model
+
+    model = build_model(depth_cut("qwen2-7b", 2))
+
+    def placed(tree):
+        return jax.tree.map(lambda l: _sds(l.shape, l.dtype, one_chip), tree)
+
+    params = placed(jax.eval_shape(model.init, jax.random.key(0)))
+    cache = placed(jax.eval_shape(lambda: model.init_cache(32, 4096)))
+    compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        params, cache, _sds((32,), jnp.int32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(l.size * l.dtype.itemsize
+                      for l in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    # the one-hot blend, undonated, needed 537710080 bytes of temp at
+    # these shapes
+    assert mem.temp_size_in_bytes < 537_710_080 // 2
+
+    moves = {"parameter", "constant", "dynamic-slice", "copy", "bitcast"}
+    kv = "32,4096,4,128]"                       # (B, S, Hkv, D)
+    seen = 0
+    for name, kind, out, ops in _fusion_ops(compiled.as_text()):
+        if kv not in out or kind != "kLoop":
+            continue
+        seen += 1
+        # no layer's K/V is stacked into a new cache or blended with a row
+        assert "[2," + kv not in out, name
+        assert ops <= moves, (name, ops - moves)
+    assert seen, "the per-layer read slice is gone: tighten this test"
