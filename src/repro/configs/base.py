@@ -20,6 +20,17 @@ class MoESpec:
     every: int = 1                 # MoE on layers where i % every == offset
     offset: int = 0
     capacity_factor: float = 1.25
+    norm_topk_prob: bool = True    # renormalise the top-k weights to sum 1
+    # the contiguous block of published experts this chip holds: experts
+    # first_held .. first_held + n_held - 1; n_held 0 => all of them.  The
+    # router keeps all n_experts outputs and top_k picks.
+    first_held: int = 0
+    n_held: int = 0
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first expert held, number held)."""
+        return self.first_held, self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -147,7 +158,7 @@ class ArchConfig:
                     + di * (dtr + 2 * ssm.state) + dtr * di + 2 * di
             if self.moe_layer(i):
                 m = self.moe
-                total += m.n_experts * 3 * d * m.d_ff
+                total += m.held[1] * 3 * d * m.d_ff
                 total += m.n_shared * 3 * d * m.shared_d_ff // max(m.n_shared, 1) \
                     if m.n_shared else 0
                 total += d * m.n_experts  # router
@@ -227,6 +238,20 @@ def depth_cut(name: str, n_layers: int) -> ArchConfig:
         raise ValueError(f"{name}: cannot cut {cfg.n_layers} layers to "
                          f"{n_layers} (period {plen})")
     return dataclasses.replace(cfg, n_layers=n_layers)
+
+
+def expert_share(cfg: ArchConfig, first: int, n_held: int) -> ArchConfig:
+    """``cfg`` holding only experts ``first .. first + n_held - 1`` of each
+    MoE layer: one chip's share of an expert-parallel group.  Every width,
+    the router's outputs and the experts per token stay as published."""
+    m = cfg.moe
+    if m is None or not (0 <= first and 0 < n_held
+                         and first + n_held <= m.n_experts):
+        raise ValueError(f"{cfg.name}: cannot hold experts {first}.."
+                         f"{first + n_held - 1} of "
+                         f"{m.n_experts if m else 0}")
+    return dataclasses.replace(
+        cfg, moe=dataclasses.replace(m, first_held=first, n_held=n_held))
 
 
 def smoke_config(name: str) -> ArchConfig:

@@ -7,6 +7,6 @@ qwen2_moe_a27b = register(ArchConfig(
     n_layers=24, d_model=2048, n_heads=16, n_kv_heads=16,
     d_ff=1408, vocab_size=151936, head_dim=128, qkv_bias=True,
     moe=MoESpec(n_experts=60, top_k=4, d_ff=1408,
-                n_shared=4, shared_d_ff=5632),
+                n_shared=4, shared_d_ff=5632, norm_topk_prob=False),
     notes="4 shared + 60 routed top-4 [hf:Qwen/Qwen1.5-MoE-A2.7B]",
 ))

@@ -1,6 +1,8 @@
 """Assigned architecture config (exact dims from the assignment table)."""
 
-from .base import ArchConfig, MoESpec, register
+import dataclasses
+
+from .base import ArchConfig, MoESpec, depth_cut, expert_share, register
 
 qwen3_moe_235b = register(ArchConfig(
     name="qwen3-moe-235b-a22b", family="moe",
@@ -11,3 +13,11 @@ qwen3_moe_235b = register(ArchConfig(
     notes="128 experts top-8 [hf:Qwen/Qwen3-30B-A3B scaled]; FSDP + bf16 "
           "moments to fit 16GB/chip at 256 chips",
 ))
+
+# One chip of an 8-chip expert-parallel group (128 experts over 8 chips,
+# experts 0-15 here) serving an 8-layer pipeline stage of the 94 layers,
+# with attention, the embedding and the LM head held whole.  Every width
+# as published.
+qwen3_moe_235b_d8_e16 = register(dataclasses.replace(
+    expert_share(depth_cut("qwen3-moe-235b-a22b", 8), 0, 16),
+    name="qwen3-moe-235b-a22b-d8-e16"))

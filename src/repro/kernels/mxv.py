@@ -34,8 +34,8 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
         return backend == "cpu"
     if interpret and backend != "cpu":
         raise ValueError(
-            f"interpret=True on the {backend!r} backend would run the crossbar "
-            "kernel in the Pallas interpreter; pass interpret=None")
+            f"interpret=True on the {backend!r} backend would run the kernel "
+            "in the Pallas interpreter; pass interpret=None")
     return bool(interpret)
 
 

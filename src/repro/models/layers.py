@@ -1,6 +1,7 @@
 """Model building blocks, pure JAX (no flax): norms, RoPE/M-RoPE, blockwise
-GQA attention (+ cached decode), SwiGLU/GeGLU MLPs, capacity-based MoE
-dispatch, and the Mamba-1 block with a chunked associative scan.
+GQA attention (+ cached decode), SwiGLU/GeGLU MLPs, MoE (capacity-based
+dispatch for training, dropless grouped matmuls over the held experts for
+serving), and the Mamba-1 block with a chunked associative scan.
 
 All functions are ``(params, x, ...) -> y`` with params as plain dicts so the
 whole model is a pytree that pjit/GSPMD can shard with per-leaf
@@ -16,6 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig, SSMSpec
+from repro.kernels.flash_attn import flash_attention
+from repro.kernels.gmm import gmm, row_tile
 Params = Dict[str, Any]
 
 
@@ -303,8 +306,10 @@ def attention(cfg: ArchConfig, p: Params, x, pos, causal: bool = True,
     """Blockwise (q-chunked) attention over the full sequence.
 
     Chunking bounds the (B, Hkv, G, qc, S) score tensor — the memory-
-    efficient-attention formulation; the Pallas flash kernel is the TPU
-    hot-spot twin validated against the same oracle.
+    efficient-attention formulation.  The serving prefill (``kv_out``:
+    positions 0 .. S-1, no gradient) runs the Pallas flash kernel instead
+    where it is causal and on one device (:func:`_flash_prefill`), so that
+    no score tensor reaches HBM.
     """
     b, s, _ = x.shape
     q, k, v = _project_qkv(cfg, p, x, x)
@@ -316,7 +321,10 @@ def attention(cfg: ArchConfig, p: Params, x, pos, causal: bool = True,
     kpos = pos[-1] if pos.ndim == 3 else pos            # (B, S) key positions
 
     mm = _mesh_axis("model")
-    if cfg.attn_shard == "seq" and mm > 1 and s % mm == 0 and causal:
+    if kv_out and causal and _ambient_mesh() is None and \
+            s % _flash_block(s) == 0:
+        o = _flash_prefill(cfg, q, k, v)
+    elif cfg.attn_shard == "seq" and mm > 1 and s % mm == 0 and causal:
         o = _seq_parallel_attention(cfg, q, k, v, kpos, scale, sdt, mm)
     else:
         o = _chunked_attention(cfg, q, k, v, kpos, scale, sdt, causal)
@@ -324,6 +332,23 @@ def attention(cfg: ArchConfig, p: Params, x, pos, causal: bool = True,
     if kv_out:
         return y, (k, v)
     return y
+
+
+def _flash_block(s: int) -> int:
+    """Query and key rows per flash-kernel block for an ``s``-long prompt."""
+    return min(512, s)
+
+
+def _flash_prefill(cfg: ArchConfig, q, k, v):
+    """Causal attention of positions 0 .. S-1 by ``kernels.flash_attn``: q
+    (B, S, Hq, D), k/v (B, S, Hkv, D) -> (B, S, Hq, D).  The products take
+    the compute dtype and accumulate in float32, as the chunked path's do on
+    the TPU's default matmul precision."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    t = lambda a: jnp.swapaxes(a, 1, 2).astype(cdt)   # (B, H, S, D)
+    blk = _flash_block(q.shape[1])
+    o = flash_attention(t(q), t(k), t(v), causal=True, bq=blk, bk=blk)
+    return jnp.swapaxes(o, 1, 2).astype(q.dtype)
 
 
 def _chunked_attention(cfg: ArchConfig, q, k, v, kpos, scale, sdt, causal):
@@ -536,14 +561,17 @@ def mlp(cfg: ArchConfig, p: Params, x):
 
 # ----------------------------------------------------------------------- MoE
 def init_moe(cfg: ArchConfig, key) -> Params:
+    """The router over every published expert; weights for the held
+    experts only (``MoESpec.held``)."""
     m = cfg.moe
     d, dt = cfg.d_model, _dtype(cfg.param_dtype)
+    n = m.held[1]
     ks = jax.random.split(key, 5)
     p: Params = {
         "router": dense_init(ks[0], (d, m.n_experts), 0, jnp.float32),
-        "w_gate": dense_init(ks[1], (m.n_experts, d, m.d_ff), 1, dt),
-        "w_up": dense_init(ks[2], (m.n_experts, d, m.d_ff), 1, dt),
-        "w_down": dense_init(ks[3], (m.n_experts, m.d_ff, d), 1, dt),
+        "w_gate": dense_init(ks[1], (n, d, m.d_ff), 1, dt),
+        "w_up": dense_init(ks[2], (n, d, m.d_ff), 1, dt),
+        "w_down": dense_init(ks[3], (n, m.d_ff, d), 1, dt),
     }
     if m.n_shared:
         sk = jax.random.split(ks[4], 2)
@@ -568,14 +596,36 @@ def _constrain_moe_groups(cfg: ArchConfig, x):
                       *([None] * (x.ndim - 1)))
 
 
+def _route(cfg: ArchConfig, p: Params, x):
+    """Softmax over every published expert, then the top k: (probs, weights,
+    expert ids), the weights renormalised where the config says so."""
+    m = cfg.moe
+    probs = jax.nn.softmax(x.astype(jnp.float32) @ p["router"], axis=-1)
+    w, idx = jax.lax.top_k(probs, m.top_k)
+    if m.norm_topk_prob:
+        w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+    return probs, w, idx
+
+
+def _shared_expert(cfg: ArchConfig, p: Params, x):
+    gate = jax.nn.sigmoid(x.astype(jnp.float32) @ p["shared_gate"])
+    return mlp(cfg, p["shared"], x) * gate.astype(x.dtype)
+
+
 def moe(cfg: ArchConfig, p: Params, x, *, capacity: Optional[int] = None):
-    """Capacity-based top-k MoE with scatter dispatch / gather combine.
+    """Capacity-based top-k MoE with scatter dispatch / gather combine: the
+    training path, whose dispatch groups the sequence-parallel layout of
+    ``lm._position_block`` shards.  Pairs beyond an expert's capacity are
+    dropped.  Needs every expert held.
 
     ``x`` is (G, T, d): G dispatch groups (token capacity is budgeted per
     group, so cumsums never cross shard boundaries when G is the sharded
     batch axis), T tokens per group.
     """
     m = cfg.moe
+    if m.held != (0, m.n_experts):
+        raise ValueError(f"{cfg.name}: capacity dispatch needs every expert "
+                         f"held, not {m.held}")
     g_, t, d = x.shape
     e, k = m.n_experts, m.top_k
     if capacity is None:
@@ -583,10 +633,7 @@ def moe(cfg: ArchConfig, p: Params, x, *, capacity: Optional[int] = None):
                                                  * m.capacity_factor))))
     c = capacity
 
-    logits = x.astype(jnp.float32) @ p["router"]        # (G, T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    w, idx = jax.lax.top_k(probs, k)                    # (G, T, K)
-    w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+    probs, w, idx = _route(cfg, p, x)                   # (G, T, K)
 
     # position of each (token, k) slot within its expert queue, per group
     onehot = jax.nn.one_hot(idx, e, dtype=jnp.int32)    # (G, T, K, E)
@@ -627,14 +674,134 @@ def moe(cfg: ArchConfig, p: Params, x, *, capacity: Optional[int] = None):
     y = y_tk.reshape(g_, t, k, d).sum(axis=2)
 
     if m.n_shared:
-        gate = jax.nn.sigmoid(x.astype(jnp.float32) @ p["shared_gate"])
-        y = y + (mlp(cfg, p["shared"], x) * gate.astype(x.dtype))
+        y = y + _shared_expert(cfg, p, x)
 
     # load-balancing auxiliary loss (Switch-style)
     me = probs.mean(axis=(0, 1))                        # (E,)
     ce = onehot.astype(jnp.float32).mean(axis=(0, 1, 2)) * e
     aux = jnp.sum(me * ce)
     return y, aux
+
+
+def moe_dropless(cfg: ArchConfig, p: Params, x, live=None, layer=None):
+    """Dropless top-k MoE over the held experts: the serving path.
+
+    ``x`` (T, d) rows, ``live`` (T,) bool or None (all live).  ``p`` is the
+    layer's parameters or, with ``layer`` (an int32 scalar), parameters
+    stacked over layers, whose expert weights the kernels then read in
+    place.  Routes every live row over all published experts, keeps the
+    (row, pick) pairs whose expert is held, sorts them by expert and runs
+    the gate, up and down projections as grouped matmuls (``kernels.gmm``),
+    so that each held expert's weights are read once per row tile its pairs
+    touch, and an expert with no pair not at all.  Each result is added back
+    to its row, weighted; no pair is dropped, and a row that is not live
+    routes nothing.  What the experts that are not held would add is left
+    out.
+
+    Under a mesh the grouped matmuls run in a ``shard_map`` over the
+    expert weights as ``sharding.rules`` lays them out (:func:`_moe_sharded`),
+    so that no device gathers another's experts.
+
+    Returns ``(y (T, d), load (n_held,) int32)``: the pairs each held expert
+    took."""
+    m = cfg.moe
+    small = {name: v for name, v in p.items() if not name.startswith("w_")}
+    if layer is not None:
+        small = jax.tree.map(lambda a: a[layer], small)
+    _, w, idx = _route(cfg, small, x)                   # (T, K)
+    if live is None:
+        live = jnp.ones(x.shape[:1], bool)
+    mesh = _ambient_mesh()
+    if mesh is None or "model" in mesh.manual_axes:
+        y, load = _held_pairs(cfg, x, w, idx, live, p, layer, m.held[0],
+                              m.held[1])
+    else:
+        y, load = _moe_sharded(cfg, mesh, x, w, idx, live, p, layer)
+    y = y.astype(x.dtype)
+    if m.n_shared:
+        y = y + _shared_expert(cfg, small, x)
+    return y, load
+
+
+def _held_pairs(cfg: ArchConfig, x, w, idx, live, p: Params, layer, first,
+                n: int, split=None):
+    """The experts ``first .. first + n - 1`` of the rows ``x`` (T, d_x),
+    whose routing is ``w``, ``idx`` (T, K): (y (T, d_y) float32, load (n,)).
+    ``p``'s expert weights hold those ``n`` experts; ``split`` names the mesh
+    axis over which ``x``'s and the gate and up weights' d axis is cut, whose
+    partial products are summed."""
+    t = x.shape[0]
+    k = cfg.moe.top_k
+    local = idx - first
+    held = (local >= 0) & (local < n) & live[:, None]
+    key = jnp.where(held, local, n).reshape(-1)         # n: not routed here
+    load = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
+    # a row picks k distinct experts, so held experts take <= min(k, n) each
+    rows = t * min(k, n)
+    rows += -rows % row_tile(rows)
+    sort = jnp.argsort(key, stable=True)
+    order = sort[:rows]
+    if order.shape[0] < rows:
+        order = jnp.pad(order, (0, rows - order.shape[0]))
+    xs = x[order // k]
+    g = gmm(xs, p["w_gate"], load, layer)
+    u = gmm(xs, p["w_up"], load, layer)
+    if split is not None:
+        g, u = jax.lax.psum((g, u), split)
+    ys = gmm(_act(cfg.mlp_act)(g) * u, p["w_down"], load, layer)
+    # each row gathers its held pairs' results back from their places in
+    # the sorted order and sums them, weighted (a gather: a scatter-add of
+    # the sorted rows is serialised on the TPU).  Rows past the held pairs
+    # were never written: select, do not scale.
+    place = jnp.minimum(jnp.argsort(sort), rows - 1).reshape(t, k)
+    yk = jnp.where(held[..., None], ys[place].astype(jnp.float32)
+                   * w[..., None], 0.0)
+    return jnp.sum(yk, axis=1), load
+
+
+def _moe_sharded(cfg: ArchConfig, mesh, x, w, idx, live, p: Params, layer):
+    """:func:`_held_pairs` in a ``shard_map`` whose expert weights come in as
+    ``sharding.rules`` places them: each device runs the experts it holds
+    (the expert axis over "model"), or its slice of every expert's d_ff, on
+    its rows; the partial outputs are summed over the axes that cut the
+    experts.  Where the rules cut d_model over "data" (FSDP), the rows come
+    whole and the gate and up products are summed over it instead."""
+    from repro.sharding.rules import batch_axes, param_specs
+
+    first, n = cfg.moe.held
+    stack = () if layer is None else (None,)
+    specs = param_specs(cfg, {"moe": {k: p[k] for k in
+                                      ("w_gate", "w_up", "w_down")}}, mesh)
+    specs = specs["moe"]
+    e_ax, d_in, f_ax = specs["w_gate"][len(stack):]
+    d_out = specs["w_down"][-1]
+    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
+    rows = tuple(a for a in batch_axes(mesh) if a in sizes)
+    n_rows = int(np.prod([sizes[a] for a in rows]))
+    if d_in is not None or x.shape[0] % n_rows:
+        rows = ()
+    row_spec = rows or None
+    cut = tuple(a for a in (e_ax, f_ax) if a is not None)
+    n_loc = n // (sizes[e_ax] if e_ax else 1)
+
+    def body(x, w, idx, live, wts, layer):
+        j = jax.lax.axis_index(e_ax) if e_ax else 0
+        y, load = _held_pairs(cfg, x, w, idx, live, wts, layer,
+                              first + j * n_loc, n_loc, d_in)
+        if cut:
+            y = jax.lax.psum(y, cut)
+        if rows:
+            load = jax.lax.psum(load, rows)
+        return y, load
+
+    P = jax.sharding.PartitionSpec
+    return jax.shard_map(
+        body,
+        in_specs=(P(row_spec, d_in), P(row_spec, None), P(row_spec, None),
+                  P(row_spec), specs, P()),
+        out_specs=(P(row_spec, d_out), P(e_ax)), check_vma=False,
+    )(x, w, idx, live, {k: p[k] for k in ("w_gate", "w_up", "w_down")},
+      jnp.asarray(0 if layer is None else layer, jnp.int32))
 
 
 # ------------------------------------------------------------------- Mamba-1

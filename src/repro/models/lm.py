@@ -97,10 +97,10 @@ def init_lm(cfg: ArchConfig, key) -> Params:
 
 
 # -------------------------------------------------------------------- forward
-def _position_block(cfg: ArchConfig, spec: Dict[str, str], p: Params, x,
-                    pos, kv_out: bool = False):
-    """One layer: pre-norm mixer + pre-norm ffn.  Returns (x, aux, extras)."""
-    aux = jnp.zeros((), jnp.float32)
+def _mixer(cfg: ArchConfig, spec: Dict[str, str], p: Params, x, pos,
+           kv_out: bool = False):
+    """Pre-norm mixer and its residual.  Returns (x, extras): with
+    ``kv_out``, the layer's K/V (attention) or (conv, ssm) state (mamba)."""
     extras = None
     h = L.apply_norm(cfg, p["norm1"], x)
     if spec["mixer"] == "attn":
@@ -113,7 +113,15 @@ def _position_block(cfg: ArchConfig, spec: Dict[str, str], p: Params, x,
             y, extras = L.mamba(cfg, p["mamba"], h, return_state=True)
         else:
             y = L.mamba(cfg, p["mamba"], h)
-    x = L.constrain_residual(cfg, x + y)
+    return L.constrain_residual(cfg, x + y), extras
+
+
+def _position_block(cfg: ArchConfig, spec: Dict[str, str], p: Params, x,
+                    pos):
+    """One layer of the training forward: mixer, then the ffn, whose MoE
+    dispatches by capacity.  Returns (x, aux)."""
+    aux = jnp.zeros((), jnp.float32)
+    x, _ = _mixer(cfg, spec, p, x, pos)
     h = L.apply_norm(cfg, p["norm2"], x)
     if spec["ffn"] == "moe":
         b, s, d = h.shape
@@ -135,61 +143,65 @@ def _position_block(cfg: ArchConfig, spec: Dict[str, str], p: Params, x,
             y, aux = L.moe(cfg, p["moe"], h.reshape(b, s, d))
     else:
         y = L.mlp(cfg, p["mlp"], h)
-    return L.constrain_residual(cfg, x + y), aux, extras
+    return L.constrain_residual(cfg, x + y), aux
 
 
-def backbone(cfg: ArchConfig, params: Params, x, pos,
-             collect_cache: bool = False):
-    """x (B, S, d) -> (h (B, S, d), aux_loss, caches|None).
+def _serve_ffn(cfg: ArchConfig, spec: Dict[str, str], p: Params, x, live,
+               stacked: Params, layer):
+    """Pre-norm ffn and its residual in the serving programs, for the layer
+    whose parameters are ``p``, period ``layer`` of the position's
+    ``stacked`` ones: MoE routes dropless over the held experts, only the
+    rows ``live`` marks (None: all), its kernels reading the expert weights
+    from ``stacked`` in place.  Returns (x, load): the pairs each held
+    expert took, or None."""
+    h = L.apply_norm(cfg, p["norm2"], x)
+    if spec["ffn"] != "moe":
+        return x + L.mlp(cfg, p["mlp"], h), None
+    b, s, d = h.shape
+    y, load = L.moe_dropless(cfg, stacked["moe"], h.reshape(b * s, d),
+                             None if live is None else live.reshape(-1),
+                             layer)
+    return x + y.reshape(b, s, d), load
 
-    ``collect_cache``: also return per-position stacked K/V (attention) or
-    (conv_state, ssm_state) (mamba) for prefill -> decode handoff.  The
-    cache path unrolls periods (scan can't easily stack heterogeneous
-    extras); the train path scans.
-    """
+
+def _no_load(cfg: ArchConfig):
+    return jnp.zeros((0, cfg.moe.held[1] if cfg.moe else 0), jnp.int32)
+
+
+def backbone(cfg: ArchConfig, params: Params, x, pos):
+    """The training forward: x (B, S, d) -> (h (B, S, d), aux_loss), one
+    scan over periods."""
     struct = period_structure(cfg)
     np_ = n_periods(cfg)
     x = L.constrain_residual(cfg, x)
 
-    if not collect_cache:
-        def period_run(x, period_params):
-            a_total = jnp.zeros((), jnp.float32)
-            for spec, p in zip(struct, period_params):
-                x, a, _ = _position_block(cfg, spec, p, x, pos)
-                a_total = a_total + a
-            return x, a_total
+    def period_run(x, period_params):
+        a_total = jnp.zeros((), jnp.float32)
+        for spec, p in zip(struct, period_params):
+            x, a = _position_block(cfg, spec, p, x, pos)
+            a_total = a_total + a
+        return x, a_total
 
-        if cfg.remat:
-            period_run = jax.checkpoint(
-                period_run, policy=_remat_policy(cfg))
+    if cfg.remat:
+        period_run = jax.checkpoint(
+            period_run, policy=_remat_policy(cfg))
 
-        if cfg.static_unroll:
-            aux = jnp.zeros((), jnp.float32)
-            for per in range(np_):
-                pp = jax.tree.map(lambda l: l[per], params["positions"])
-                x, a = period_run(x, pp)
-                aux = aux + a
-        else:
-            def period_body(carry, period_params):
-                x, aux = carry
-                x, a = period_run(x, period_params)
-                return (x, aux + a), None
-
-            (x, aux), _ = jax.lax.scan(
-                period_body, (x, jnp.zeros((), jnp.float32)),
-                params["positions"])
-        return L.apply_norm(cfg, params["final_norm"], x), aux, None
-
-    caches: List[List] = [[] for _ in struct]
-    aux = jnp.zeros((), jnp.float32)
-    for per in range(np_):
-        for pos_i, spec in enumerate(struct):
-            p = jax.tree.map(lambda a: a[per], params["positions"][pos_i])
-            x, a, extra = _position_block(cfg, spec, p, x, pos, kv_out=True)
+    if cfg.static_unroll:
+        aux = jnp.zeros((), jnp.float32)
+        for per in range(np_):
+            pp = jax.tree.map(lambda l: l[per], params["positions"])
+            x, a = period_run(x, pp)
             aux = aux + a
-            caches[pos_i].append(extra)
-    stacked = [jax.tree.map(lambda *xs: jnp.stack(xs), *c) for c in caches]
-    return L.apply_norm(cfg, params["final_norm"], x), aux, stacked
+    else:
+        def period_body(carry, period_params):
+            x, aux = carry
+            x, a = period_run(x, period_params)
+            return (x, aux + a), None
+
+        (x, aux), _ = jax.lax.scan(
+            period_body, (x, jnp.zeros((), jnp.float32)),
+            params["positions"])
+    return L.apply_norm(cfg, params["final_norm"], x), aux
 
 
 def run_periods(cfg: ArchConfig, body, carry, stacked, n: int):
@@ -216,7 +228,7 @@ def run_stack(cfg: ArchConfig, positions, x, pos):
 
     def period_run(x, period_params):
         for spec, p in zip(struct, period_params):
-            x, _, _ = _position_block(cfg, spec, p, x, pos)
+            x, _ = _position_block(cfg, spec, p, x, pos)
         return x
 
     if cfg.static_unroll:
@@ -280,7 +292,7 @@ def lm_loss(cfg: ArchConfig, params: Params, batch) -> Tuple[jax.Array, Dict]:
     if pos is None:
         pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
     x = embed_tokens(cfg, params, tokens)
-    h, aux, _ = backbone(cfg, params, x, pos)
+    h, aux = backbone(cfg, params, x, pos)
     ce = chunked_ce_loss(cfg, params, h, batch["labels"])
     loss = ce + 0.01 * aux
     return loss, {"ce": ce, "aux": aux}
@@ -315,13 +327,17 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> Dict:
             "length": jnp.zeros((batch,), jnp.int32)}
 
 
-def decode_step(cfg: ArchConfig, params: Params, cache: Dict, tokens):
-    """One token for every sequence.  tokens (B,) int32 (or (B, d) embeds).
+def decode_step(cfg: ArchConfig, params: Params, cache: Dict, tokens,
+                live=None):
+    """One token for every sequence.  tokens (B,) int32 (or (B, d) embeds);
+    ``live`` (B,) bool marks the rows MoE layers route (None: all).
 
-    Returns (logits (B, V), new_cache).  Attention positions carry their
-    stacked K/V through the scan over periods and write only each slot's
-    new row, so a donated cache is updated in place; Mamba states are
-    small and pass through as scanned inputs and outputs.
+    Returns (logits (B, V), new_cache, load): ``load`` (n_moe_layers,
+    n_held) int32 counts the pairs each held expert took, in layer order.
+    Attention positions carry their stacked K/V through the scan over
+    periods and write only each slot's new row, so a donated cache is
+    updated in place; Mamba states are small and pass through as scanned
+    inputs and outputs.
     """
     struct = period_structure(cfg)
     np_ = n_periods(cfg)
@@ -331,54 +347,74 @@ def decode_step(cfg: ArchConfig, params: Params, cache: Dict, tokens):
     else:
         x = params["embed"][tokens][:, None]            # (B, 1, d)
 
-    def ffn(spec, p, x):
-        h = L.apply_norm(cfg, p["norm2"], x)
-        if spec["ffn"] == "moe":
-            y, _ = L.moe(cfg, p["moe"], h.swapaxes(0, 1))  # (1, B, d) group
-            return x + y.swapaxes(0, 1)
-        return x + L.mlp(cfg, p["mlp"], h)
-
-    new_layers = []
+    new_layers, loads = [], []
     for pos_i, spec in enumerate(struct):
         p_stacked = params["positions"][pos_i]
         c_stacked = cache["layers"][pos_i]
+        # the expert weights stay stacked: the kernels read a period's in
+        # place, where the scan's slice would copy them whole
+        p_xs = {n: v for n, v in p_stacked.items() if n != "moe"}
 
         if spec["mixer"] == "attn":
-            def body(carry, per, spec=spec):
+            def body(carry, per, spec=spec, p_stacked=p_stacked):
                 x, c = carry
                 p, i = per
                 h = L.apply_norm(cfg, p["norm1"], x)
                 y, c = L.attention_decode(cfg, p["attn"], h, c, length,
                                           layer=i)
-                return (ffn(spec, p, x + y), c), None
+                x, load = _serve_ffn(cfg, spec, p, x + y, live, p_stacked, i)
+                return (x, c), load
 
-            (x, new_c), _ = run_periods(cfg, body, (x, c_stacked),
-                                        (p_stacked, jnp.arange(np_)), np_)
+            (x, new_c), load = run_periods(cfg, body, (x, c_stacked),
+                                           (p_xs, jnp.arange(np_)), np_)
         else:
-            def body(x, per, spec=spec):
-                p, c = per
+            def body(x, per, spec=spec, p_stacked=p_stacked):
+                p, c, i = per
                 h = L.apply_norm(cfg, p["norm1"], x)
                 y, nconv, nssm = L.mamba_decode(
                     cfg, p["mamba"], h, c["conv"], c["ssm"])
-                return ffn(spec, p, x + y), {"conv": nconv, "ssm": nssm}
+                x, load = _serve_ffn(cfg, spec, p, x + y, live, p_stacked, i)
+                return x, ({"conv": nconv, "ssm": nssm}, load)
 
-            x, new_c = run_periods(cfg, body, x, (p_stacked, c_stacked), np_)
+            x, (new_c, load) = run_periods(
+                cfg, body, x, (p_xs, c_stacked, jnp.arange(np_)), np_)
         new_layers.append(new_c)
+        if load is not None:
+            loads.append(load)                          # (P, n_held)
 
     h = L.apply_norm(cfg, params["final_norm"], x)[:, 0]   # (B, d)
     w = unembed_matrix(cfg, params)
     logits = h.astype(jnp.float32) @ w.astype(jnp.float32).T
-    return logits, {"layers": new_layers, "length": length + 1}
+    load = (jnp.stack(loads, axis=1).reshape(-1, loads[0].shape[-1])
+            if loads else _no_load(cfg))
+    return logits, {"layers": new_layers, "length": length + 1}, load
 
 
-def prefill(cfg: ArchConfig, params: Params, tokens, max_len: int):
-    """Process a full prompt; return (last_logits (B, V), filled cache)."""
+def prefill(cfg: ArchConfig, params: Params, tokens, max_len: int,
+            live=None):
+    """Process a full prompt; return (last_logits (B, V), filled cache,
+    load).  ``live`` (B, S) bool marks the rows MoE layers route (None:
+    all); ``load`` is as in :func:`decode_step`.  Periods are unrolled,
+    each layer handing on its K/V (attention) or (conv, ssm) state."""
     b, s = tokens.shape[:2]
     pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    x = embed_tokens(cfg, params, tokens)
-    h, _, extras = backbone(cfg, params, x, pos, collect_cache=True)
-
+    x = L.constrain_residual(cfg, embed_tokens(cfg, params, tokens))
     struct = period_structure(cfg)
+    extras: List[List] = [[] for _ in struct]
+    loads = []
+    for per in range(n_periods(cfg)):
+        for pos_i, spec in enumerate(struct):
+            stacked = params["positions"][pos_i]
+            p = jax.tree.map(lambda a: a[per], stacked)
+            x, extra = _mixer(cfg, spec, p, x, pos, kv_out=True)
+            x, load = _serve_ffn(cfg, spec, p, x, live, stacked, per)
+            x = L.constrain_residual(cfg, x)
+            extras[pos_i].append(extra)
+            if load is not None:
+                loads.append(load)
+    extras = [jax.tree.map(lambda *xs: jnp.stack(xs), *e) for e in extras]
+    h = L.apply_norm(cfg, params["final_norm"], x)
+
     cdt = jnp.dtype(cfg.compute_dtype)
     entries = []
     for pos_i, spec in enumerate(struct):
@@ -404,4 +440,4 @@ def prefill(cfg: ArchConfig, params: Params, tokens, max_len: int):
              "length": jnp.full((b,), s, jnp.int32)}
     w = unembed_matrix(cfg, params)
     logits = h[:, -1].astype(jnp.float32) @ w.astype(jnp.float32).T
-    return logits, cache
+    return logits, cache, jnp.stack(loads) if loads else _no_load(cfg)

@@ -26,6 +26,7 @@ The three programs have stable names: ``jit_decode_step``, ``jit_prefill``
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Dict, List, Optional
 
@@ -39,8 +40,7 @@ from repro.models import build_model
 
 # the batcher's spans; one request's spans share its ``rid``
 SPANS = ("batcher.submit",   # submit: rid
-         "batcher.admit",    # token upload, prefill dispatch, the wait for
-                             #   the previous insert, insert dispatch:
+         "batcher.admit",    # token upload, prefill and insert dispatch:
                              #   rid, bucket, n (true prompt length)
          "batcher.decode",   # token upload and decode dispatch: live, queued
          "batcher.fetch",    # wait for the decode program, copy logits out
@@ -72,11 +72,12 @@ def _buckets(n: int, sizes=(16, 32, 64, 128, 256, 512, 1024, 2048, 4096)):
     return sizes[-1]
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0,))
 def insert_slot(cache: Any, one_cache: Any, slot) -> Any:
     """``cache`` with the single-sequence ``one_cache`` written into batch
-    slot ``slot`` (a traced int32: one program for every slot).  Out of
-    place: the cache is not donated."""
+    slot ``slot`` (a traced int32: one program for every slot).  In place:
+    the cache is donated, so the call writes one slot's rows and the cache
+    it was given is gone after."""
     def ins(batch_leaf, one_leaf):
         if batch_leaf.ndim == 1:                         # length (B,)
             return batch_leaf.at[slot].set(one_leaf[0])
@@ -98,7 +99,14 @@ class ContinuousBatcher:
     * ``prefills``: admitted requests; ``prefill_tokens`` their true prompt
       tokens and ``prefill_padded_tokens`` the bucket tokens prefilled;
     * ``queue_wait_s``: admission minus submission time on the host's
-      ``perf_counter``, summed over admitted requests.
+      ``perf_counter``, summed over admitted requests;
+    * ``moe_pairs``: (row, pick) pairs the decode steps routed to held
+      experts, summed over MoE layers and steps; ``moe_experts_hit``: held
+      (layer, expert) entries that took at least one pair, summed over
+      steps; ``moe_prefill_pairs``: the pairs the prefills routed.  Idle
+      slots and bucket padding route nothing.  The programs return the
+      load beside their output, and it comes to the host with the decode
+      step's logits.
     """
 
     def __init__(self, cfg: ArchConfig, n_slots: int, max_len: int,
@@ -118,11 +126,14 @@ class ContinuousBatcher:
         self.last_tok = np.zeros((n_slots,), np.int32)
         self.stats = {"steps": 0, "prefills": 0, "slot_busy_ticks": 0,
                       "queued_ticks": 0, "prefill_tokens": 0,
-                      "prefill_padded_tokens": 0, "queue_wait_s": 0.0}
+                      "prefill_padded_tokens": 0, "queue_wait_s": 0.0,
+                      "moe_pairs": 0, "moe_experts_hit": 0,
+                      "moe_prefill_pairs": 0}
         self._submitted: Dict[int, float] = {}          # id(req) -> time
+        self._prefill_loads: List[Any] = []             # not yet fetched
 
-        def decode_step(p, c, t):
-            return self.model.decode_step(p, c, t)
+        def decode_step(p, c, t, live):
+            return self.model.decode_step(p, c, t, live)
         # the cache is donated: the step writes each slot's new K/V row
         # into it in place, and the cache it was given is gone after
         self._decode = jax.jit(decode_step, donate_argnums=(1,))
@@ -134,12 +145,14 @@ class ContinuousBatcher:
             def prefill(p, tokens, true_len):
                 # tokens (1, bucket); run full-bucket prefill, then reset
                 # length to the true prompt length (suffix is padding that
-                # the length mask hides from future attention)
-                logits_last, cache = self.model.prefill(
-                    p, {"tokens": tokens}, self.max_len)
+                # the length mask hides from future attention, and that
+                # MoE layers do not route)
+                live = jnp.arange(bucket)[None] < true_len
+                logits_last, cache, load = self.model.prefill(
+                    p, {"tokens": tokens}, self.max_len, live)
                 cache["length"] = jnp.full((1,), true_len, jnp.int32)
                 # logits at the true last token, not the padded tail
-                return cache
+                return cache, load
             self._prefill_cache[bucket] = jax.jit(prefill)
         return self._prefill_cache[bucket]
 
@@ -164,13 +177,9 @@ class ContinuousBatcher:
                                  n=sp):
                 toks = np.zeros((1, bucket), np.int32)
                 toks[0, :sp] = req.prompt
-                cache1 = self._prefill_fn(bucket)(
+                cache1, load = self._prefill_fn(bucket)(
                     self.params, jnp.asarray(toks), sp)
-                # an insert's new cache is allocated when it is dispatched,
-                # and the cache it replaces is freed only once the device
-                # has run it: wait for the previous insert, so that serial
-                # admissions hold two caches, not one each
-                jax.block_until_ready(self.cache)
+                self._prefill_loads.append(load)
                 self.cache = insert_slot(self.cache, cache1, np.int32(slot))
             self.slots[slot] = req
             st = self.stats
@@ -196,10 +205,18 @@ class ContinuousBatcher:
         st["queued_ticks"] += len(self.queue)
         with TraceAnnotation("batcher.decode", live=len(live),
                              queued=len(self.queue)):
-            logits, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(self.last_tok))
+            mask = np.zeros((self.n_slots,), bool)
+            mask[live] = True
+            logits, self.cache, load = self._decode(
+                self.params, self.cache, jnp.asarray(self.last_tok),
+                jnp.asarray(mask))
         with TraceAnnotation("batcher.fetch"):
-            logits = np.asarray(logits)
+            logits, load, pre = jax.device_get(
+                (logits, load, self._prefill_loads))
+        self._prefill_loads = []
+        st["moe_pairs"] += int(load.sum())
+        st["moe_experts_hit"] += int(np.count_nonzero(load))
+        st["moe_prefill_pairs"] += sum(int(x.sum()) for x in pre)
         with TraceAnnotation("batcher.sample"):
             for i in live:
                 req = self.slots[i]

@@ -76,7 +76,7 @@ def _param_rule(cfg: ArchConfig, path: str, shape: Tuple[int, ...],
         if leaf == "down":
             return spec("model", fsdp)
     if parent == "moe":
-        e = cfg.moe.n_experts if cfg.moe else 0
+        e = cfg.moe.held[1] if cfg.moe else 0            # experts held
         ep = _div(e, m)                                  # expert parallelism
         # seq mode: tokens (dispatch groups) carry the model-axis
         # parallelism, so non-EP expert weights must not shard a

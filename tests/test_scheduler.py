@@ -97,6 +97,28 @@ def test_eos_frees_slot_early(setup):
     assert r1.out == [eos] and r1.done
 
 
+def test_insert_slot_writes_one_slot_in_place(setup):
+    """The insert takes the batch cache donated: the cache it was given is
+    gone after, and the new one is the old one with the slot's rows (and
+    length) replaced, every other slot bit for bit."""
+    cfg, eng = setup
+    cb = ContinuousBatcher(cfg, n_slots=3, max_len=64, params=eng.params)
+    fill = lambda c, v: jax.tree.map(lambda a: a + np.asarray(v, a.dtype), c)
+    cache = fill(cb.cache, 1)
+    one = fill(jax.tree.map(lambda a: a[:, :1] if a.ndim > 1 else a[:1],
+                            cb.cache), 7)
+    before = jax.tree.map(np.array, cache)               # copies, not views
+    new = scheduler.insert_slot(cache, one, np.int32(1))
+    assert all(a.is_deleted() for a in jax.tree.leaves(cache))
+    for b, n in zip(jax.tree.leaves(before), jax.tree.leaves(new)):
+        n = np.asarray(n)
+        if n.ndim == 1:                                  # length (B,)
+            np.testing.assert_array_equal(n, [1, 7, 1])
+        else:                                            # (P, B, ...)
+            np.testing.assert_array_equal(n[:, [0, 2]], b[:, [0, 2]])
+            assert (n[:, 1] == 7).all()
+
+
 # ------------------------------------------------------------ observability
 # three requests through two slots, all submitted at once: A and B are
 # admitted at step 1, A retires at step 2, C takes its slot at step 3, B
@@ -221,7 +243,10 @@ def test_stats_counters_exact(traced):
     assert st.pop("queue_wait_s") > 0
     assert st == {"steps": 4, "prefills": 3, "slot_busy_ticks": 7,
                   "queued_ticks": 2, "prefill_tokens": sum(n),
-                  "prefill_padded_tokens": 16 + 32 + 16}
+                  "prefill_padded_tokens": 16 + 32 + 16,
+                  # a dense model routes nothing
+                  "moe_pairs": 0, "moe_experts_hit": 0,
+                  "moe_prefill_pairs": 0}
 
 
 def test_queue_wait_matches_spans(traced):
