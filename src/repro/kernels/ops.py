@@ -11,7 +11,6 @@ from __future__ import annotations
 
 from . import ref
 from .conv2d import crossbar_conv2d
-from .decode_attn import flash_decode
 from .flash_attn import flash_attention
 from .mamba_scan import selective_scan
 from .mxv import crossbar_mxv, crossbar_mxv_int8
@@ -44,12 +43,6 @@ def attention(q, k, v, causal: bool = True, use_kernel: bool = False, **kw):
     if use_kernel:
         return flash_attention(q, k, v, causal=causal, **kw)
     return ref.attention_ref(q, k, v, causal=causal)
-
-
-def decode_attention(q, k, v, length, use_kernel: bool = False, **kw):
-    if use_kernel:
-        return flash_decode(q, k, v, length, **kw)
-    return ref.decode_ref(q, k, v, length)
 
 
 def mamba_scan(u, dt, a, b, c, d_skip, use_kernel: bool = False, **kw):

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig, SSMSpec
+from repro.kernels.decode_attn import decode_attn, reads_in_place
 from repro.kernels.flash_attn import flash_attention
 from repro.kernels.gmm import gmm, row_tile
 Params = Dict[str, Any]
@@ -488,8 +489,10 @@ def kv_dequantize(q, scale, dtype):
     return (q.astype(jnp.float32) * scale).astype(dtype)
 
 
-def attention_decode(cfg: ArchConfig, p: Params, x, cache, length, layer):
-    """One-token decode: x (B, 1, d); length (B,).
+def attention_decode(cfg: ArchConfig, p: Params, x, cache, length, layer,
+                     live=None):
+    """One-token decode: x (B, 1, d); length (B,); ``live`` (B,) bool marks
+    the slots that attend (None: all).
 
     ``cache`` holds ``k``/``v`` (P, B, S, Hkv, D) stacked over periods (or
     layers), plus ``k_scale``/``v_scale`` (P, B, S, Hkv, 1) when the cache
@@ -498,11 +501,19 @@ def attention_decode(cfg: ArchConfig, p: Params, x, cache, length, layer):
 
     Writes each slot's new K/V row at ``length`` and nowhere else (a slot at
     ``length >= S`` gets no write), then attends over positions <= length.
+    A slot outside ``live`` attends nothing: its attention output is zero.
     Returns (y (B,1,d), the written cache).  Only the new rows are written,
-    so a donated cache is updated in place.
+    so a donated cache is updated in place.  On one device with a cache in
+    the compute dtype whose (Hkv, D) a TPU keeps in rows
+    (:func:`repro.kernels.decode_attn.reads_in_place`) the attention is the
+    Pallas kernel ``kernels.decode_attn``, which reads period ``layer`` in
+    place and only each live slot's blocks up to its length.  Otherwise
+    (a mesh, an int8 cache, a head shape the TPU lays out otherwise) it is
+    :func:`_decode_attn_xla`, which slices the period out and attends over
+    all S for every slot.
     """
     b = x.shape[0]
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    hq, hd = cfg.n_heads, cfg.hd
     quant = cfg.kv_dtype == "int8"
     q, k, v = _project_qkv(cfg, p, x, x)                # (B,1,H,D)
     pos = length[:, None]                               # (B,1)
@@ -520,23 +531,38 @@ def attention_decode(cfg: ArchConfig, p: Params, x, cache, length, layer):
     cache = {n: c.at[layer, jnp.arange(b), length].set(
                  rows[n][:, 0].astype(c.dtype), mode="drop")
              for n, c in cache.items()}
+    if (not quant and _ambient_mesh() is None
+            and reads_in_place(cfg.n_kv_heads, hd, cache["k"].dtype)):
+        o = decode_attn(q[:, 0], cache["k"], cache["v"], layer, length, live)
+    else:
+        o = _decode_attn_xla(cache, q[:, 0], layer, length, live)
+    y = o.reshape(b, 1, hq * hd).astype(x.dtype) @ p["wo"]
+    return y, cache
+
+
+def _decode_attn_xla(cache, q, layer, length, live=None):
+    """Decode attention as XLA ops, for a mesh, an int8 cache or a head
+    shape the kernel does not read in place: q (B, Hq,
+    D) over period ``layer`` of ``cache``, sliced out (and dequantized),
+    by f32 einsums over all S positions masked to <= length; zero where
+    ``live`` is false.  Returns (B, Hq, D) float32."""
     c = {n: l[layer] for n, l in cache.items()}
-    if quant:
+    if "k_scale" in c:
         k_eff = c["k"].astype(jnp.float32) * c["k_scale"]   # fused dequant
         v_eff = c["v"].astype(jnp.float32) * c["v_scale"]
     else:
         k_eff, v_eff = c["k"], c["v"]
-
-    g = hq // hkv
-    qg = q.reshape(b, hkv, g, hd)                       # squeeze Sq=1
+    b, hq, hd = q.shape
+    hkv = k_eff.shape[2]
+    qg = q.reshape(b, hkv, hq // hkv, hd)
     s = jnp.einsum("bhgd,bkhd->bhgk", qg.astype(jnp.float32),
                    k_eff.astype(jnp.float32)) / np.sqrt(hd)
     mask = (jnp.arange(k_eff.shape[1])[None] <= length[:, None])
     s = jnp.where(mask[:, None, None], s, -1e30)
     pr = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgk,bkhd->bhgd", pr, v_eff.astype(jnp.float32))
-    y = o.reshape(b, 1, hq * hd).astype(x.dtype) @ p["wo"]
-    return y, cache
+    o = o.reshape(b, hq, hd)
+    return o if live is None else jnp.where(live[:, None, None], o, 0.0)
 
 
 # ---------------------------------------------------------------------- MLPs

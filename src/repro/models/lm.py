@@ -330,7 +330,8 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> Dict:
 def decode_step(cfg: ArchConfig, params: Params, cache: Dict, tokens,
                 live=None):
     """One token for every sequence.  tokens (B,) int32 (or (B, d) embeds);
-    ``live`` (B,) bool marks the rows MoE layers route (None: all).
+    ``live`` (B,) bool marks the rows that attend and that MoE layers
+    route (None: all); a row outside it gives logits no one reads.
 
     Returns (logits (B, V), new_cache, load): ``load`` (n_moe_layers,
     n_held) int32 counts the pairs each held expert took, in layer order.
@@ -361,7 +362,7 @@ def decode_step(cfg: ArchConfig, params: Params, cache: Dict, tokens,
                 p, i = per
                 h = L.apply_norm(cfg, p["norm1"], x)
                 y, c = L.attention_decode(cfg, p["attn"], h, c, length,
-                                          layer=i)
+                                          layer=i, live=live)
                 x, load = _serve_ffn(cfg, spec, p, x + y, live, p_stacked, i)
                 return (x, c), load
 

@@ -96,6 +96,10 @@ class ContinuousBatcher:
     * ``steps``: decode steps; ``slot_busy_ticks``: live slots summed over
       them (``slot_busy_ticks / (steps * n_slots)`` is slot utilization);
     * ``queued_ticks``: requests still queued at each decode step, summed;
+    * ``kv_positions``: cache positions the live slots attended, summed
+      over decode steps (a slot at length n attends n + 1, at most
+      ``max_len``); ``kv_positions / (steps * n_slots * max_len)`` is the
+      share of the cache the decode attention reads;
     * ``prefills``: admitted requests; ``prefill_tokens`` their true prompt
       tokens and ``prefill_padded_tokens`` the bucket tokens prefilled;
     * ``queue_wait_s``: admission minus submission time on the host's
@@ -125,7 +129,8 @@ class ContinuousBatcher:
         self.queue: List[Request] = []
         self.last_tok = np.zeros((n_slots,), np.int32)
         self.stats = {"steps": 0, "prefills": 0, "slot_busy_ticks": 0,
-                      "queued_ticks": 0, "prefill_tokens": 0,
+                      "queued_ticks": 0, "kv_positions": 0,
+                      "prefill_tokens": 0,
                       "prefill_padded_tokens": 0, "queue_wait_s": 0.0,
                       "moe_pairs": 0, "moe_experts_hit": 0,
                       "moe_prefill_pairs": 0}
@@ -222,6 +227,9 @@ class ContinuousBatcher:
                 req = self.slots[i]
                 tok = self._slot_logits_token(logits[i])
                 req.out.append(tok)
+                # it attended its prompt, the tokens before tok and its new row
+                st["kv_positions"] += min(len(req.prompt) + len(req.out),
+                                          self.max_len)
                 self.last_tok[i] = tok
                 if (self.eos is not None and tok == self.eos) or \
                         len(req.out) >= req.max_new:

@@ -15,7 +15,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ref
 from repro.kernels.conv2d import crossbar_conv2d
-from repro.kernels.decode_attn import flash_decode
+from repro.kernels.decode_attn import decode_attn
 from repro.kernels.flash_attn import flash_attention
 from repro.kernels.mamba_scan import selective_scan
 from repro.kernels.mxv import crossbar_mxv, crossbar_mxv_int8
@@ -119,12 +119,25 @@ def test_flash_attention_bf16():
     (1, 8, 2, 256, 64, 128, 200),
     (4, 4, 4, 512, 32, 128, 512),
     (2, 16, 2, 256, 64, 64, 17),
+    # a cache length no multiple of the block: the last block is ragged
+    (2, 8, 2, 200, 64, 64, 190),
+    (1, 8, 4, 200, 32, 64, 70),
 ])
 def test_flash_decode_sweep(b, hq, hkv, s, d, bk, length):
+    """``decode_attn`` on period 1 of a cache stacked over two, laid out
+    (P, B, S, Hkv, D) as the model's; ``length`` positions attended, so
+    the last is ``length - 1``."""
     q = RNG.normal(size=(b, hq, d)).astype(np.float32)
     k = RNG.normal(size=(b, hkv, s, d)).astype(np.float32)
     v = RNG.normal(size=(b, hkv, s, d)).astype(np.float32)
-    y = flash_decode(q, k, v, length, bk=bk)
+
+    def stacked(x):
+        x = np.swapaxes(x, 1, 2)                          # (B, S, Hkv, D)
+        return jnp.asarray(np.stack([RNG.normal(size=x.shape), x]),
+                           jnp.float32)
+
+    y = decode_attn(jnp.asarray(q), stacked(k), stacked(v), 1,
+                    jnp.full((b,), length - 1, jnp.int32), bk=bk)
     want = ref.decode_ref(q, k, v, length)
     np.testing.assert_allclose(np.asarray(y), np.asarray(want),
                                rtol=2e-3, atol=2e-3)

@@ -241,8 +241,13 @@ def test_stats_counters_exact(traced):
     assert all(r.done for r in reqs)
     st = dict(counted)
     assert st.pop("queue_wait_s") > 0
+    # each live slot attends its prompt, the tokens it has decoded and
+    # its new row: A and B at steps 1-2, C and B at step 3, C at step 4
+    kv = (n[0] + 1) + (n[1] + 1) + (n[0] + 2) + (n[1] + 2) + \
+        (n[2] + 1) + (n[1] + 3) + (n[2] + 2)
     assert st == {"steps": 4, "prefills": 3, "slot_busy_ticks": 7,
-                  "queued_ticks": 2, "prefill_tokens": sum(n),
+                  "queued_ticks": 2, "kv_positions": kv,
+                  "prefill_tokens": sum(n),
                   "prefill_padded_tokens": 16 + 32 + 16,
                   # a dense model routes nothing
                   "moe_pairs": 0, "moe_experts_hit": 0,

@@ -106,15 +106,23 @@ def _fusion_ops(hlo: str):
         yield m.group(1), m.group(3), m.group(2), ops
 
 
-def test_qwen2_7b_decode_updates_donated_cache_in_place(one_chip):
+def test_qwen2_7b_decode_updates_donated_cache_in_place(one_chip,
+                                                        monkeypatch):
     """Two qwen2-7b layers at the serving cell's 32 slots x 4096 positions,
     jitted with the cache donated as ``ContinuousBatcher`` does: the output
-    cache is the input's buffers, and no fusion rewrites K/V beyond the new
-    rows.  The per-layer read slice (``dynamic-slice``, and a layout copy
-    of it for the attention) is the one copy of the cache left."""
+    cache is the input's buffers, and nothing copies a layer's K/V out of
+    it.  The scan over periods runs one ``decode_attn`` kernel a layer,
+    which reads the stacked cache in place, so no (B, S, Hkv, D) buffer is
+    left in the program (the jnp path sliced one per layer per tensor, and
+    copied it again for its einsum), and the temporaries stay far under one
+    such slice."""
     from repro.configs.base import depth_cut
+    from repro.kernels import decode_attn
     from repro.models import build_model
 
+    # the backend here is the CPU: compile the kernel, not its interpreter
+    monkeypatch.setattr(decode_attn, "resolve_interpret",
+                        lambda interpret: False)
     model = build_model(depth_cut("qwen2-7b", 2))
 
     def placed(tree):
@@ -128,21 +136,20 @@ def test_qwen2_7b_decode_updates_donated_cache_in_place(one_chip):
     cache_bytes = sum(l.size * l.dtype.itemsize
                       for l in jax.tree.leaves(cache))
     assert mem.alias_size_in_bytes >= cache_bytes
-    # the one-hot blend, undonated, needed 537710080 bytes of temp at
-    # these shapes
-    assert mem.temp_size_in_bytes < 537_710_080 // 2
+    kv_slice = 32 * 4096 * 4 * 128 * 2          # one layer's K, bf16
+    assert mem.temp_size_in_bytes < kv_slice // 64
 
+    hlo = compiled.as_text()
+    assert len(re.findall(r"%decode_attn(\.\d+)? = .*custom-call\(", hlo)) == 1
+    assert len(re.findall(r" while\(", hlo)) == 1     # the scan over layers
+    assert not re.search(r"\[32,4096,4,128\]", hlo)   # (B, S, Hkv, D)
     moves = {"parameter", "constant", "dynamic-slice", "copy", "bitcast"}
-    kv = "32,4096,4,128]"                       # (B, S, Hkv, D)
-    seen = 0
-    for name, kind, out, ops in _fusion_ops(compiled.as_text()):
-        if kv not in out or kind != "kLoop":
-            continue
-        seen += 1
-        # no layer's K/V is stacked into a new cache or blended with a row
-        assert "[2," + kv not in out, name
-        assert ops <= moves, (name, ops - moves)
-    assert seen, "the per-layer read slice is gone: tighten this test"
+    kv = "32,4096,4,128]"
+    for name, kind, out, ops in _fusion_ops(hlo):
+        if kv in out and kind == "kLoop":
+            # no layer's K/V is stacked into a new cache or blended
+            assert "[2," + kv not in out, name
+            assert ops <= moves, (name, ops - moves)
 
 
 # grouped matmul rows at the qwen3-moe share's decode (32 slots x top-8) and
@@ -164,14 +171,17 @@ def test_moe_gmm_compiles_to_mosaic(one_chip, rows, k, n):
 def test_qwen3_moe_share_decode_step_compiles_one_layer(one_chip,
                                                         monkeypatch):
     """One layer of the qwen3-moe chip share (16 of 128 experts) at the
-    serving cell's 32 slots x 4096, cache donated: the expert layer is three
-    ``moe_gmm`` kernels, and the step fits the chip."""
+    serving cell's 32 slots x 4096, cache donated: the attention is one
+    ``decode_attn`` kernel, the expert layer three ``moe_gmm`` kernels, and
+    the step fits the chip."""
     from repro.configs.base import depth_cut
-    from repro.kernels import gmm
+    from repro.kernels import decode_attn, gmm
     from repro.models import build_model
 
-    # the backend here is the CPU: compile the kernel, not its interpreter
-    monkeypatch.setattr(gmm, "resolve_interpret", lambda interpret: False)
+    # the backend here is the CPU: compile the kernels, not their
+    # interpreter
+    for mod in (gmm, decode_attn):
+        monkeypatch.setattr(mod, "resolve_interpret", lambda interpret: False)
 
     cfg = depth_cut("qwen3-moe-235b-a22b-d8-e16", 1)
     assert cfg.moe.held == (0, 16)
@@ -187,8 +197,68 @@ def test_qwen3_moe_share_decode_step_compiles_one_layer(one_chip,
         _sds((32,), jnp.bool_, one_chip)).compile()
     hlo = compiled.as_text()
     assert len(re.findall(r"%moe_gmm(\.\d+)? = .*custom-call\(", hlo)) == 3
+    assert len(re.findall(r"%decode_attn(\.\d+)? = .*custom-call\(", hlo)) == 1
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 4 * 2**30
+
+
+def _decoder_heads():
+    """One registered decoder for each (Hq, Hkv, D) the registry holds."""
+    from repro.configs.base import all_archs, get_arch
+
+    seen = {}
+    for name in all_archs():
+        cfg = get_arch(name)
+        if cfg.n_heads:
+            seen.setdefault((cfg.n_heads, cfg.n_kv_heads, cfg.hd), name)
+    return sorted(seen.values())
+
+
+@pytest.mark.parametrize("name", _decoder_heads())
+def test_decode_attention_compiles_for_every_decoder(one_chip, monkeypatch,
+                                                     name):
+    """One decode attention layer of each registered decoder's heads at 32
+    slots x 4096 positions over two periods, cache donated.  Where the TPU
+    keeps the cache's (Hkv, D) in rows (``reads_in_place``) the attention
+    is one ``decode_attn`` kernel, which fits VMEM and copies no K/V;
+    elsewhere it stays on the XLA path, and the kernel alone would have
+    relaid the whole cache out first."""
+    from repro.configs.base import get_arch
+    from repro.kernels import decode_attn as da
+    from repro.models import layers as L
+
+    monkeypatch.setattr(da, "resolve_interpret", lambda interpret: False)
+    cfg = get_arch(name)
+    hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    dt = jnp.dtype(cfg.compute_dtype)
+    in_rows = da.reads_in_place(hkv, d, dt)
+    params = jax.tree.map(lambda l: _sds(l.shape, l.dtype, one_chip),
+                          jax.eval_shape(lambda: L.init_attention(
+                              cfg, jax.random.key(0))))
+    kv = _sds((2, 32, 4096, hkv, d), dt, one_chip)
+    cache_bytes = 2 * 2 * 32 * 4096 * hkv * d * dt.itemsize
+    slots = (_sds((32,), jnp.int32, one_chip), _sds((32,), jnp.bool_,
+                                                    one_chip))
+    compiled = jax.jit(
+        lambda p, x, c, n, live: L.attention_decode(cfg, p, x, c, n, 1,
+                                                    live),
+        donate_argnums=(2,)).lower(
+        params, _sds((32, 1, cfg.d_model), dt, one_chip),
+        {"k": kv, "v": kv}, *slots).compile()
+    hlo = compiled.as_text()
+    kernels = len(re.findall(r"%decode_attn(\.\d+)? = .*custom-call\(", hlo))
+    assert kernels == int(in_rows)
+    if in_rows:
+        # the cache updated in place, and neither a period sliced out of it
+        # nor the cache relaid
+        assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+        assert f"[32,4096,{hkv},{d}]" not in hlo
+        assert not re.search(rf"\[2,32,4096,{hkv},{d}\]\S* copy\(", hlo)
+    else:
+        alone = jax.jit(da.decode_attn).lower(
+            _sds((32, hq, d), dt, one_chip), kv, kv,
+            _sds((), jnp.int32, one_chip), *slots).compile()
+        assert alone.memory_analysis().temp_size_in_bytes >= cache_bytes // 2
 
 
 def test_qwen3_moe_share_prefill_compiles_with_flash_attention(one_chip,
@@ -225,7 +295,8 @@ def test_qwen3_moe_expert_parallel_decode_compiles(topo, monkeypatch):
     """One qwen3-moe layer at published widths, all 128 experts over a
     (1 data, 4 model) mesh of the described v5e as ``sharding.rules`` lays
     them out: each chip runs its 32 experts' ``moe_gmm`` kernels on its own
-    weights, and no expert weight is gathered."""
+    weights, and no expert weight is gathered.  Under a mesh the decode
+    attention stays on the XLA path: no ``decode_attn`` kernel."""
     import numpy as np
     from jax.sharding import AxisType, Mesh, NamedSharding
 
@@ -255,5 +326,6 @@ def test_qwen3_moe_expert_parallel_decode_compiles(topo, monkeypatch):
             params, cache, _sds((32,), jnp.int32, rep),
             _sds((32,), jnp.bool_, rep)).compile().as_text()
     assert len(re.findall(r"%moe_gmm(\.\d+)? = .*custom-call\(", hlo)) == 3
+    assert not re.search(r"%decode_attn(\.\d+)? = .*custom-call\(", hlo)
     gathered = re.findall(r"= \w+\[([\d,]*)\]\S* all-gather\(", hlo)
     assert not [g for g in gathered if "1536" in g.split(",")], gathered
