@@ -1,6 +1,6 @@
 """Benchmark 4: Pallas kernel wall-time (interpret mode on CPU — a
-correctness-side proxy; the TPU numbers come from the dry-run roofline) and
-achieved-vs-oracle consistency."""
+correctness-side proxy, never a TPU number) and achieved-vs-oracle
+consistency."""
 
 from __future__ import annotations
 

@@ -1,6 +1,5 @@
 """Benchmark 5: host-side train/serve throughput on reduced configs — the
-end-to-end sanity row (the at-scale numbers live in EXPERIMENTS.md roofline,
-derived from the dry-run)."""
+end-to-end sanity row, never a TPU number."""
 
 from __future__ import annotations
 

@@ -72,41 +72,12 @@ class ArchConfig:
     adam_dtype: str = "float32"
     fsdp: bool = False             # shard params over 'data' too (ZeRO-3 style)
     remat: bool = True
-    remat_policy: str = "full"     # full | dots (save matmul outputs)
     q_chunk: int = 512             # blockwise-attention query chunk
     ssm_chunk: int = 256           # chunked associative scan length
-    # Dry-run only: unroll every lax.scan/map into straight-line HLO so
-    # compiled.cost_analysis() counts all iterations (XLA costs while-loop
-    # bodies ONCE; see EXPERIMENTS.md §Dry-run caveats).  Never used on the
-    # host paths — unrolled 94-layer graphs are compile-time hostile.
-    static_unroll: bool = False
-    # Attention-internal sharding (hillclimb; see EXPERIMENTS.md §Perf).
-    #   default   — leave layout to GSPMD (head_dim gets sharded when heads
-    #               don't divide the model axis => giant score all-reduce);
-    #   replicate — constrain q/k/v to batch-only sharding (scores local);
-    #   seq       — context-parallel: q and scores sharded over the model
-    #               axis on the *query-sequence* dim, k/v replicated (the
-    #               GQA long-context layout).
-    attn_shard: str = "default"
-    # Cross-device reduction dtype for attention scores path: bf16 halves
-    # any score-sized collective and score HBM traffic (MXU accumulates in
-    # f32 regardless; CPU oracle tolerance in tests covers the delta).
-    scores_dtype: str = "float32"
-    # Causal flop bounding: q-chunk i only multiplies against keys that can
-    # be unmasked for it (a *static* slice when chunks are unrolled).  With
-    # attn_shard="seq" the sequence is STRIPED across the model axis (row j
-    # of group g has global position j*mm + g) so the key bound is uniform
-    # over groups — work stays balanced AND ~45% of attention flops vanish.
-    causal_bound: bool = False
     # Decode KV-cache dtype: "compute" stores K/V in compute_dtype; "int8"
     # stores symmetric per-(position, kv-head) int8 with an f32 scale —
-    # halves the cache-read traffic that dominates decode (§Perf pair B).
+    # halves the cache-read traffic that dominates decode.
     kv_dtype: str = "compute"
-    # With attn_shard="seq": also keep the residual stream sequence-sharded
-    # between blocks (full sequence parallelism).  False = CP inside
-    # attention only, Megatron-style replicated residual for the MLP —
-    # cheaper backward (no sharded-token weight-grad contraction).
-    seq_residual: bool = True
     # Gradient accumulation: >1 selects the microbatched train step
     # (distributed.overlap.make_accum_train_step) — per-microbatch bucket
     # reductions overlap the next microbatch's backward.
@@ -202,7 +173,7 @@ SHAPES: Dict[str, ShapeSpec] = {
 
 
 def shapes_for(cfg: ArchConfig) -> List[str]:
-    """Applicable shape cells for an architecture (skips noted in DESIGN.md)."""
+    """Applicable shape cells for an architecture."""
     out = ["train_4k", "prefill_32k", "decode_32k"]
     # long_500k needs sub-quadratic attention: run only for SSM/hybrid.
     if cfg.family in ("ssm", "hybrid"):

@@ -16,7 +16,7 @@ added back into step t+1's gradient, so SGD/Adam converge to the same point
 (tested on a quadratic in tests/test_compression.py).
 
 Everything is a pure function over pytrees — usable inside jit/shard_map,
-dry-runnable with ShapeDtypeStructs.
+lowerable with ShapeDtypeStructs.
 """
 
 from __future__ import annotations

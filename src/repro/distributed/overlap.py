@@ -1,7 +1,6 @@
 """Compute/communication overlap primitives.
 
-Two mechanisms, both visible structurally in lowered HLO (the dry-run's
-"profile"):
+Two mechanisms, both visible structurally in lowered HLO:
 
 1. ``ring_all_reduce`` — an explicit bidirectional-ring all-reduce built
    from ``lax.ppermute`` (reduce-scatter sweep + all-gather sweep, chunked).

@@ -1,5 +1,5 @@
 """Pipelined prefill over the pod axis — the paper's execution model on the
-multi-pod mesh (§Perf pair C).
+multi-pod mesh.
 
 The CM accelerator runs inference as a *layer pipeline*: every core holds
 its layers' weights permanently and a compiled LCU state machine advances
@@ -15,30 +15,20 @@ each core as its input dependencies are satisfied (paper §2/§3).  Here:
 Execution: ``shard_map`` manual over "pod", auto over ("data","model") so
 each stage's interior still uses the full 256-chip GSPMD layout.
 
-What this buys (the paper's motivation, quantified in EXPERIMENTS.md):
-per-pod resident weight bytes divided by n_stages — the multi-pod machine
-can hold a model n_stages x larger with inter-pod traffic bounded by one
-activation hop per microbatch per tick, at pipeline utilization
-n_micro / (n_micro + n_stages - 1).
-
-Run: PYTHONPATH=src python -m repro.launch.pipeline_prefill \
-        --arch qwen2-7b --micro 4 [--seq-len 32768] [--batch 32]
+What this buys (the paper's motivation): per-pod resident weight bytes
+divided by n_stages — the multi-pod machine can hold a model n_stages x
+larger with inter-pod traffic bounded by one activation hop per microbatch
+per tick, at pipeline utilization n_micro / (n_micro + n_stages - 1).
 """
 
-import argparse
 import dataclasses
-import json
-import os
-import time
-from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import sharding as sh
-from repro.configs.base import ArchConfig, get_arch
-from repro.configs import archs  # noqa: F401
+from repro.configs.base import ArchConfig
 from repro.core import pipeline
 from repro.models import lm
 
@@ -171,77 +161,3 @@ def pipeline_param_init(cfg: ArchConfig, n_stages: int, shardings):
         return stages, embed[None]
 
     return jax.jit(init, out_shardings=tuple(shardings[:2]))
-
-
-def main():
-    # a compile-only tool: the production multi-pod mesh as host devices
-    os.environ.setdefault("XLA_FLAGS",
-                          "--xla_force_host_platform_device_count=512")
-    from repro.launch.cache import enable_compile_cache
-    from repro.launch.mesh import make_production_mesh
-    from repro.launch.roofline import parse_collectives
-
-    enable_compile_cache()
-
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="qwen2-7b")
-    ap.add_argument("--micro", type=int, default=2)
-    ap.add_argument("--seq-len", type=int, default=32_768)
-    ap.add_argument("--batch", type=int, default=32)
-    ap.add_argument("--depth", type=int, default=0,
-                    help="override layers per stage (0 = full depth)")
-    ap.add_argument("--variant", default="baseline",
-                    help="extra overrides name: baseline|seq_causal")
-    ap.add_argument("--out", default="experiments/hillclimb")
-    args = ap.parse_args()
-
-    cfg = get_arch(args.arch)
-    ov: Dict[str, Any] = {}
-    if args.variant == "seq_causal":
-        ov = {"attn_shard": "seq", "causal_bound": True}
-    if args.depth:
-        ov["n_layers"] = args.depth * 2                # per-stage depth x2
-    ov["static_unroll"] = False                        # scan periods
-    cfg = dataclasses.replace(cfg, **ov)
-
-    mesh = make_production_mesh(multi_pod=True)
-    t0 = time.time()
-    fn, sds, in_sh, sched = make_pipelined_prefill(
-        cfg, mesh, args.micro, args.seq_len, args.batch)
-    with jax.set_mesh(mesh):
-        compiled = jax.jit(fn, in_shardings=in_sh).lower(*sds).compile()
-    cost = compiled.cost_analysis()
-    hlo = compiled.as_text()
-    colls = parse_collectives(hlo)
-    mem = {}
-    try:
-        ma = compiled.memory_analysis()
-        mem = {k: int(getattr(ma, k)) for k in (
-            "argument_size_in_bytes", "output_size_in_bytes",
-            "temp_size_in_bytes") if hasattr(ma, k)}
-    except Exception as e:
-        mem = {"error": str(e)}
-    rec = {
-        "arch": args.arch, "mode": "pipelined_prefill",
-        "variant": args.variant,
-        "n_stages": mesh.shape["pod"], "n_micro": args.micro,
-        "schedule_ticks": sched.n_ticks,
-        "schedule_utilization": sched.utilization(),
-        "flops_per_device": float(cost.get("flops", 0.0)),
-        "bytes_per_device": float(cost.get("bytes accessed", 0.0)),
-        "coll_bytes_per_device": sum(v["bytes"] for v in colls.values()),
-        "collectives": colls,
-        "memory": mem,
-        "compile_s": round(time.time() - t0, 1),
-    }
-    print(json.dumps(rec, indent=1))
-    os.makedirs(args.out, exist_ok=True)
-    with open(os.path.join(
-            args.out,
-            f"{args.arch}_pipeline_{args.variant}_m{args.micro}.json"),
-            "w") as f:
-        json.dump(rec, f, indent=1)
-
-
-if __name__ == "__main__":
-    main()

@@ -1,7 +1,7 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
 Batched prefill + decode over the ServeEngine; reduced configs run on CPU,
-full configs target the production mesh proven by the dry-run.
+full configs target the production mesh.
 """
 
 from __future__ import annotations

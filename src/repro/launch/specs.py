@@ -1,8 +1,8 @@
 """ShapeDtypeStruct stand-ins + shardings for every (arch x shape x step).
 
-``input_specs(arch, shape)`` returns weak-type-correct, shardable SDS trees
-for the step function being lowered — no device allocation ever happens in
-the dry-run (the shannon/kernels pattern).
+``make_cell(arch, shape, mesh)`` returns weak-type-correct, shardable SDS
+trees for the step function being lowered — no device allocation ever
+happens, so a production mesh of host devices can compile a full-size cell.
 """
 
 from __future__ import annotations
@@ -23,7 +23,7 @@ SDS = jax.ShapeDtypeStruct
 
 
 class CellSpec(NamedTuple):
-    """Everything dryrun needs to lower one (arch x shape) cell."""
+    """Everything needed to lower one (arch x shape) cell."""
     fn: Any                  # callable to jit
     args: Tuple              # SDS trees
     in_shardings: Tuple      # NamedSharding trees
@@ -59,12 +59,8 @@ def batch_sds(cfg: ArchConfig, shape: ShapeSpec) -> Dict[str, SDS]:
     return batch
 
 
-def make_cell(arch: str, shape_name: str, mesh: Mesh,
-              overrides: Dict | None = None) -> CellSpec:
+def make_cell(arch: str, shape_name: str, mesh: Mesh) -> CellSpec:
     cfg = get_arch(arch)
-    if overrides:
-        import dataclasses
-        cfg = dataclasses.replace(cfg, **overrides)
     shape = SHAPES[shape_name]
     model = build_model(cfg)
     psds = params_sds(model)

@@ -2,7 +2,7 @@
 
 Runs the fault-tolerant Trainer on a (possibly reduced) config over however
 many local devices exist; on a real cluster the same entrypoint runs under
-the production mesh (the dry-run proves the shardings).
+the production mesh.
 """
 
 from __future__ import annotations

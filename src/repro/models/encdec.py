@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from . import layers as L
-from .lm import _stack, chunked_ce_loss, run_periods
+from .lm import _stack, chunked_ce_loss
 
 Params = Dict[str, Any]
 
@@ -74,7 +74,7 @@ def encode(cfg: ArchConfig, params: Params, embeds):
             run = jax.checkpoint(run)
         return run(x), None
 
-    x, _ = run_periods(cfg, body, x, params["encoder"], cfg.encoder_layers)
+    x, _ = jax.lax.scan(body, x, params["encoder"])
     return L.apply_norm(cfg, params["enc_final_norm"], x)
 
 
@@ -97,7 +97,7 @@ def decode_train(cfg: ArchConfig, params: Params, tokens, enc_out):
             run = jax.checkpoint(run)
         return run(x), None
 
-    x, _ = run_periods(cfg, body, x, params["decoder"], cfg.n_layers)
+    x, _ = jax.lax.scan(body, x, params["decoder"])
     return L.apply_norm(cfg, params["final_norm"], x)
 
 
@@ -145,8 +145,7 @@ def encdec_prefill(cfg: ArchConfig, params: Params, embeds, tokens,
         x = x + L.mlp(cfg, p["mlp"], h)
         return x, (k, v, xk, xv)
 
-    x, (ks, vs, xks, xvs) = run_periods(cfg, body, x, params["decoder"],
-                                        cfg.n_layers)
+    x, (ks, vs, xks, xvs) = jax.lax.scan(body, x, params["decoder"])
     h = L.apply_norm(cfg, params["final_norm"], x)
     cdt = jnp.dtype(cfg.compute_dtype)
     pad = [(0, 0), (0, 0), (0, max_len - s), (0, 0), (0, 0)]
@@ -179,10 +178,10 @@ def encdec_decode_step(cfg: ArchConfig, params: Params, cache: Dict, tokens):
         x = x + L.mlp(cfg, p["mlp"], h)
         return (x, kv), None
 
-    (x, kv), _ = run_periods(
-        cfg, body, (x, {"k": cache["k"], "v": cache["v"]}),
+    (x, kv), _ = jax.lax.scan(
+        body, (x, {"k": cache["k"], "v": cache["v"]}),
         (params["decoder"], cache["xk"], cache["xv"],
-         jnp.arange(cfg.n_layers)), cfg.n_layers)
+         jnp.arange(cfg.n_layers)))
     h = L.apply_norm(cfg, params["final_norm"], x)[:, 0]
     w = params["embed"]
     logits = h.astype(jnp.float32) @ w.astype(jnp.float32).T

@@ -76,13 +76,11 @@ def _rope_hd_pin(x):
     device a *fraction of a head*, i.e. head_dim itself is sharded, and
     XLA's SPMD partitioner miscompiles the cross-shard split/concat of the
     rotate-half — k comes out wrong by O(1), not ulps (observed on jaxlib
-    0.4.x CPU; tests/test_attn_variants.py guards the whole layout matrix
-    against the unsharded oracle).  With heads divisible (whole heads per
-    device, the common q case) the pin is skipped — no reshard cost.  Every
-    pinned dim is named — PartitionSpec.UNCONSTRAINED entries are
-    themselves mishandled by this partitioner (verified on the MoE combine
-    gather), so the pin replicates S/H/D and lets downstream constraints
-    re-shard."""
+    0.4.x CPU).  With heads divisible (whole heads per device, the common q
+    case) the pin is skipped — no reshard cost.  Every pinned dim is named
+    — PartitionSpec.UNCONSTRAINED entries are themselves mishandled by this
+    partitioner (verified on the MoE combine gather), so the pin replicates
+    S/H/D and lets downstream constraints re-shard."""
     mm = _mesh_axis("model")
     if mm <= 1 or x.shape[-2] % mm == 0:
         return x
@@ -178,33 +176,6 @@ def _constrain(x, *spec):
     return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
-def _attn_constraints(cfg: ArchConfig, q, k, v):
-    """Apply cfg.attn_shard layout to rope'd q/k/v (B, S, H, D) tensors.
-
-    replicate — batch-only sharding: the score einsum contracts over an
-      unsharded head_dim, so no score-sized all-reduce can appear; GSPMD
-      all-gathers k/v (tiny for GQA) instead.
-    seq — context parallelism: queries (and thus scores/outputs) shard the
-      *query-sequence* dim over "model"; k/v replicate.  This is the GQA
-      long-context layout — compute stays 16-way parallel AND no score
-      reduction exists.
-    """
-    if cfg.attn_shard == "default":
-        return q, k, v
-    baxes = _ambient_batch_axes()
-    if baxes is None:
-        return q, k, v
-    b = baxes if q.shape[0] % _axes_size(baxes) == 0 else None
-    if cfg.attn_shard == "replicate":
-        q = _constrain(q, b, None, None, None)
-    elif cfg.attn_shard == "seq":
-        sq = "model" if q.shape[1] % _mesh_axis("model") == 0 else None
-        q = _constrain(q, b, sq, None, None)
-    k = _constrain(k, b, None, None, None)
-    v = _constrain(v, b, None, None, None)
-    return q, k, v
-
-
 def _axes_size(axes: Tuple[str, ...]) -> int:
     mesh = _ambient_mesh()
     if mesh is None:
@@ -221,22 +192,6 @@ def _mesh_axis(name: str) -> int:
     if mesh is None:
         return 1
     return dict(zip(mesh.axis_names, mesh.axis_sizes)).get(name, 1)
-
-
-def constrain_residual(cfg: ArchConfig, x):
-    """Sequence-parallel residual stream (attn_shard == "seq"): (B, S, d)
-    constrained to (batch-axes, "model", None).  Norms/MLP/projections are
-    pointwise over tokens, so the whole block runs 16-way parallel over the
-    sequence with *weights* gathered (small) instead of activations
-    all-reduced (huge)."""
-    if cfg.attn_shard != "seq" or not cfg.seq_residual or x.ndim != 3:
-        return x
-    baxes = _ambient_batch_axes()
-    if baxes is None:
-        return x
-    b = baxes if x.shape[0] % _axes_size(baxes) == 0 else None
-    s = "model" if x.shape[1] % _mesh_axis("model") == 0 else None
-    return _constrain(x, b, s, None)
 
 
 # ----------------------------------------------------------------- attention
@@ -279,25 +234,21 @@ def _project_qkv(cfg: ArchConfig, p: Params, xq, xkv):
     return q, k, v
 
 
-def _gqa_scores_softmax_out(q, k, v, mask, scale, scores_dtype=jnp.float32):
+def _gqa_scores_softmax_out(q, k, v, mask, scale):
     """q (B,Sq,Hq,D), k/v (B,Skv,Hkv,D), mask broadcastable (B,1,1,Sq,Skv).
-
-    ``scores_dtype`` bf16 keeps the score tensor (and any collective that
-    lands on it) half-size; the softmax max/sum runs in f32 either way.
-    """
+    Scores, softmax and products in float32."""
     b, sq, hq, hd = q.shape
     skv, hkv = k.shape[1], k.shape[2]
     g = hq // hkv
     qg = q.reshape(b, sq, hkv, g, hd)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(scores_dtype),
-                   k.astype(scores_dtype),
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
+                   k.astype(jnp.float32),
                    preferred_element_type=jnp.float32) * scale
-    s = jnp.where(mask, s, -1e30)                      # (B,Hkv,G,Sq,Skv) f32
+    s = jnp.where(mask, s, -1e30)                      # (B,Hkv,G,Sq,Skv)
     pmax = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - jax.lax.stop_gradient(pmax)).astype(scores_dtype)
-    z = jnp.sum(e.astype(jnp.float32), axis=-1, keepdims=True)
-    pr = (e.astype(jnp.float32) / z).astype(scores_dtype)
-    o = jnp.einsum("bhgqk,bkhd->bqhgd", pr, v.astype(scores_dtype),
+    e = jnp.exp(s - jax.lax.stop_gradient(pmax))
+    pr = e / jnp.sum(e, axis=-1, keepdims=True)
+    o = jnp.einsum("bhgqk,bkhd->bqhgd", pr, v.astype(jnp.float32),
                    preferred_element_type=jnp.float32)
     return o.reshape(b, sq, hq, hd).astype(q.dtype)
 
@@ -316,19 +267,14 @@ def attention(cfg: ArchConfig, p: Params, x, pos, causal: bool = True,
     q, k, v = _project_qkv(cfg, p, x, x)
     q = positional_rotate(cfg, q, pos)
     k = positional_rotate(cfg, k, pos)
-    q, k, v = _attn_constraints(cfg, q, k, v)
-    sdt = jnp.dtype(cfg.scores_dtype)
     scale = 1.0 / np.sqrt(cfg.hd)
     kpos = pos[-1] if pos.ndim == 3 else pos            # (B, S) key positions
 
-    mm = _mesh_axis("model")
     if kv_out and causal and _ambient_mesh() is None and \
             s % _flash_block(s) == 0:
         o = _flash_prefill(cfg, q, k, v)
-    elif cfg.attn_shard == "seq" and mm > 1 and s % mm == 0 and causal:
-        o = _seq_parallel_attention(cfg, q, k, v, kpos, scale, sdt, mm)
     else:
-        o = _chunked_attention(cfg, q, k, v, kpos, scale, sdt, causal)
+        o = _chunked_attention(cfg, q, k, v, kpos, scale, causal)
     y = o.reshape(b, s, cfg.n_heads * cfg.hd) @ p["wo"]
     if kv_out:
         return y, (k, v)
@@ -352,101 +298,26 @@ def _flash_prefill(cfg: ArchConfig, q, k, v):
     return jnp.swapaxes(o, 1, 2).astype(q.dtype)
 
 
-def _chunked_attention(cfg: ArchConfig, q, k, v, kpos, scale, sdt, causal):
+def _chunked_attention(cfg: ArchConfig, q, k, v, kpos, scale, causal):
     b, s = q.shape[:2]
     qc = min(cfg.q_chunk, s)
     if s % qc:
         qc = s                                          # odd sizes: one chunk
     n_chunks = s // qc
-    # causal flop bounding is only meaningful for the standard layout where
-    # row r of chunk i has global position i*qc + r (positions ascending)
-    bound = cfg.causal_bound and causal and cfg.static_unroll and n_chunks > 1
 
     def chunk(i):
         qi = jax.lax.dynamic_slice_in_dim(q, i * qc, qc, axis=1)
         qpos = jax.lax.dynamic_slice_in_dim(kpos, i * qc, qc, axis=1)
-        ki, vi, kpi = k, v, kpos
-        if bound:                                       # static key bound
-            hi = (i + 1) * qc
-            ki = jax.lax.slice_in_dim(k, 0, hi, axis=1)
-            vi = jax.lax.slice_in_dim(v, 0, hi, axis=1)
-            kpi = jax.lax.slice_in_dim(kpos, 0, hi, axis=1)
         if causal:
-            m = (qpos[:, :, None] >= kpi[:, None, :])[:, None, None]
+            m = (qpos[:, :, None] >= kpos[:, None, :])[:, None, None]
         else:
-            m = jnp.ones((b, 1, 1, qc, ki.shape[1]), bool)
-        return _gqa_scores_softmax_out(qi, ki, vi, m, scale, sdt)
+            m = jnp.ones((b, 1, 1, qc, k.shape[1]), bool)
+        return _gqa_scores_softmax_out(qi, k, v, m, scale)
 
     if n_chunks == 1:
         return chunk(0)
-    if cfg.static_unroll:
-        return jnp.concatenate([chunk(i) for i in range(n_chunks)], axis=1)
     o = jax.lax.map(chunk, jnp.arange(n_chunks))        # (N, B, qc, Hq, D)
     return jnp.moveaxis(o, 0, 1).reshape(b, s, cfg.n_heads, cfg.hd)
-
-
-def _seq_parallel_attention(cfg: ArchConfig, q, k, v, kpos, scale, sdt,
-                            mm: int):
-    """Context parallelism: queries grouped into ``mm`` shard-aligned
-    sequence groups constrained to the "model" axis; k/v replicated over
-    "model" (cheap for GQA — Hkv*hd << Hq*hd).  Scores never cross devices:
-    the score einsum contracts over an UNSHARDED head_dim and its output is
-    sharded on the query-group axis, so the giant score all-reduce of the
-    default layout cannot appear.  Causality stays exact: masks use the real
-    global positions carried by ``kpos``."""
-    b, s, hq, hd = q.shape
-    baxes = _ambient_batch_axes()
-    bspec = baxes if (baxes and b % _axes_size(baxes) == 0) else None
-    sl = s // mm
-    striped = cfg.causal_bound
-    if striped:
-        # STRIPED assignment: group g owns rows {g, g+mm, g+2mm, ...} so all
-        # groups' chunk i covers global positions < (i+1)*qc*mm — the causal
-        # key bound is uniform across groups (balanced) and static.
-        q4 = jnp.moveaxis(q.reshape(b, sl, mm, hq, hd), 2, 1)
-        pos4 = jnp.moveaxis(kpos.reshape(b, sl, mm), 2, 1)
-    else:
-        # BLOCKED assignment: group g owns rows [g*sl, (g+1)*sl)
-        q4 = q.reshape(b, mm, sl, hq, hd)
-        pos4 = kpos.reshape(b, mm, sl)
-    q4 = _constrain(q4, bspec, "model", None, None, None)
-    k = _constrain(k, bspec, None, None, None)
-    v = _constrain(v, bspec, None, None, None)
-    qc = min(cfg.q_chunk, sl)
-    if sl % qc:
-        qc = sl
-    n_chunks = sl // qc
-    bound = striped and cfg.static_unroll and n_chunks > 1
-
-    def chunk(i):
-        qi = jax.lax.dynamic_slice_in_dim(q4, i * qc, qc, axis=2)
-        qpos = jax.lax.dynamic_slice_in_dim(pos4, i * qc, qc, axis=2)
-        ki, vi, kpi = k, v, kpos
-        if bound:                                        # static, uniform
-            hi = (i + 1) * qc * mm
-            ki = jax.lax.slice_in_dim(k, 0, hi, axis=1)
-            vi = jax.lax.slice_in_dim(v, 0, hi, axis=1)
-            kpi = jax.lax.slice_in_dim(kpos, 0, hi, axis=1)
-        m = (qpos[..., None] >= kpi[:, None, None, :])   # (B, mm, qc, Skv')
-
-        def one_group(qg, mg):                           # (B,qc,H,D),(B,qc,S')
-            return _gqa_scores_softmax_out(
-                qg, ki, vi, mg[:, None, None], scale, sdt)
-
-        return jax.vmap(one_group, in_axes=(1, 1), out_axes=1)(qi, m)
-
-    if n_chunks == 1:
-        o = chunk(0)
-    elif cfg.static_unroll:
-        o = jnp.concatenate([chunk(i) for i in range(n_chunks)], axis=2)
-    else:
-        o = jax.lax.map(chunk, jnp.arange(n_chunks))     # (N,B,mm,qc,H,D)
-        o = jnp.moveaxis(o, 0, 3)                        # (B,mm,N,qc,H,D)
-        o = o.reshape(b, mm, sl, hq, hd)
-    if striped:
-        return jnp.moveaxis(o.reshape(b, mm, sl, hq, hd), 1, 2
-                            ).reshape(b, s, hq, hd)
-    return o.reshape(b, s, hq, hd)
 
 
 def cross_attention(cfg: ArchConfig, p: Params, x, kv_cache):
@@ -606,22 +477,6 @@ def init_moe(cfg: ArchConfig, key) -> Params:
     return p
 
 
-def _constrain_moe_groups(cfg: ArchConfig, x):
-    """In seq mode, keep the dispatch-group axis sharded over
-    (batch-axes, model) through the capacity buffer — otherwise GSPMD
-    replicates the expert einsums when expert weights are replicated."""
-    if cfg.attn_shard != "seq" or not cfg.seq_residual:
-        return x
-    baxes = _ambient_batch_axes()
-    if baxes is None:
-        return x
-    total = _axes_size(baxes) * _mesh_axis("model")
-    if total <= 1 or x.shape[0] % total:
-        return x
-    return _constrain(x, tuple(baxes) + ("model",),
-                      *([None] * (x.ndim - 1)))
-
-
 def _route(cfg: ArchConfig, p: Params, x):
     """Softmax over every published expert, then the top k: (probs, weights,
     expert ids), the weights renormalised where the config says so."""
@@ -640,9 +495,8 @@ def _shared_expert(cfg: ArchConfig, p: Params, x):
 
 def moe(cfg: ArchConfig, p: Params, x, *, capacity: Optional[int] = None):
     """Capacity-based top-k MoE with scatter dispatch / gather combine: the
-    training path, whose dispatch groups the sequence-parallel layout of
-    ``lm._position_block`` shards.  Pairs beyond an expert's capacity are
-    dropped.  Needs every expert held.
+    training path.  Pairs beyond an expert's capacity are dropped.  Needs
+    every expert held.
 
     ``x`` is (G, T, d): G dispatch groups (token capacity is budgeted per
     group, so cumsums never cross shard boundaries when G is the sharded
@@ -674,27 +528,22 @@ def moe(cfg: ArchConfig, p: Params, x, *, capacity: Optional[int] = None):
     buf = jnp.zeros((g_, e * c + 1, d), x.dtype)
     buf = jax.vmap(lambda b_, s_, v_: b_.at[s_].add(v_))(
         buf, slot, x_rep * keep[..., None].astype(x.dtype))
-    xe = _constrain_moe_groups(
-        cfg, buf[:, :e * c].reshape(g_, e, c, d))
+    xe = buf[:, :e * c].reshape(g_, e, c, d)
 
     a = _act(cfg.mlp_act)
     h = a(jnp.einsum("gecd,edf->gecf", xe, p["w_gate"])) * \
         jnp.einsum("gecd,edf->gecf", xe, p["w_up"])
-    ye = _constrain_moe_groups(
-        cfg, jnp.einsum("gecf,efd->gecd", h, p["w_down"]))  # (G, E, C, d)
+    ye = jnp.einsum("gecf,efd->gecd", h, p["w_down"])   # (G, E, C, d)
 
     flat = jnp.concatenate(
         [ye.reshape(g_, e * c, d), jnp.zeros((g_, 1, d), ye.dtype)], axis=1)
     # Pin the combine gather's operand to a fully-named layout (group axis
     # sharded as dispatched, expert rows + d replicated): an expert-sharded
     # or UNCONSTRAINED-annotated row dim feeds an XLA SPMD gather miscompile
-    # on jaxlib 0.4.x (y_tk off by O(1), not ulps) — see
-    # tests/test_attn_variants.py's oracle check.
-    pinned = _constrain_moe_groups(cfg, flat)
-    if pinned is flat:                   # helper bailed (non-seq mode, no
-        baxes = _ambient_batch_axes()    # mesh, or indivisible groups):
-        b_ax = baxes if baxes and g_ % _axes_size(baxes) == 0 else None
-        pinned = _constrain(flat, b_ax, None, None)
+    # on jaxlib 0.4.x (y_tk off by O(1), not ulps).
+    baxes = _ambient_batch_axes()
+    b_ax = baxes if baxes and g_ % _axes_size(baxes) == 0 else None
+    pinned = _constrain(flat, b_ax, None, None)
     y_tk = jax.vmap(lambda f_, s_: f_[s_])(pinned, slot)  # (G, T*K, d)
     y_tk = y_tk * (w.reshape(g_, t * k, 1) * keep[..., None]).astype(y_tk.dtype)
     y = y_tk.reshape(g_, t, k, d).sum(axis=2)
@@ -853,7 +702,7 @@ def init_mamba(cfg: ArchConfig, key) -> Params:
     }
 
 
-def _ssm_scan_chunked(u, dt, a, bm, cm, chunk: int, unroll: bool = False):
+def _ssm_scan_chunked(u, dt, a, bm, cm, chunk: int):
     """h_t = exp(dt_t A) h_{t-1} + (dt_t u_t) B_t ;  y_t = (h_t C_t).sum(N).
 
     Associative scan within chunks of ``chunk`` steps, sequential lax.scan
@@ -891,14 +740,7 @@ def _ssm_scan_chunked(u, dt, a, bm, cm, chunk: int, unroll: bool = False):
           jnp.moveaxis(bc, 1, 0).astype(jnp.float32),
           jnp.moveaxis(cc, 1, 0).astype(jnp.float32))
     h0 = jnp.zeros((b, d, n), jnp.float32)
-    if unroll:
-        h, ys_l = h0, []
-        for i in range(nc):
-            h, y = chunk_step(h, jax.tree.map(lambda t: t[i], xs))
-            ys_l.append(y)
-        hT, ys = h, jnp.stack(ys_l)
-    else:
-        hT, ys = jax.lax.scan(chunk_step, h0, xs)
+    hT, ys = jax.lax.scan(chunk_step, h0, xs)
     y = jnp.moveaxis(ys, 0, 1).reshape(b, l, d)
     return y, hT
 
@@ -937,8 +779,7 @@ def mamba(cfg: ArchConfig, p: Params, x, state: Optional[Tuple] = None,
                          + p["dt_b"].astype(x.dtype))   # (B, S, Din)
     a = -jnp.exp(p["A_log"])                            # (Din, N)
 
-    y, hT = _ssm_scan_chunked(xa, dt, a, bm, cm, cfg.ssm_chunk,
-                              unroll=cfg.static_unroll)
+    y, hT = _ssm_scan_chunked(xa, dt, a, bm, cm, cfg.ssm_chunk)
     y = y + p["D"][None, None] * xa.astype(jnp.float32)
     y = (y.astype(x.dtype)) * jax.nn.silu(z)
     out = y @ p["out_proj"]

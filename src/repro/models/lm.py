@@ -2,10 +2,12 @@
 
 An architecture is a repeating *period* of layer kinds (e.g. jamba's
 ``MMMMAMMM`` with MoE on odd positions).  Parameters for each position within
-the period are stacked across periods along a leading axis, and the model
-body is a single ``lax.scan`` over periods whose body unrolls the period's
+the period are stacked across periods along a leading axis.  The model body
+runs two ways: the training forward, a pipeline stage and the decode step
+are each one ``lax.scan`` over periods whose body unrolls the period's
 positions — HLO size stays O(period), not O(n_layers), which keeps 94-layer
-configs compilable at 512 devices.
+configs compilable at 512 devices — and the serving prefill unrolls every
+period, each layer handing on its cache entry.
 
 Caches for decode mirror the same structure: per period-position, leaves
 stacked over periods.  Decode carries the stacked K/V through its scan and
@@ -22,12 +24,6 @@ from repro.configs.base import ArchConfig
 from . import layers as L
 
 Params = Dict[str, Any]
-
-
-def _remat_policy(cfg: ArchConfig):
-    if cfg.remat_policy == "dots":
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    return None
 
 
 # ------------------------------------------------------------------ structure
@@ -113,7 +109,7 @@ def _mixer(cfg: ArchConfig, spec: Dict[str, str], p: Params, x, pos,
             y, extras = L.mamba(cfg, p["mamba"], h, return_state=True)
         else:
             y = L.mamba(cfg, p["mamba"], h)
-    return L.constrain_residual(cfg, x + y), extras
+    return x + y, extras
 
 
 def _position_block(cfg: ArchConfig, spec: Dict[str, str], p: Params, x,
@@ -124,26 +120,10 @@ def _position_block(cfg: ArchConfig, spec: Dict[str, str], p: Params, x,
     x, _ = _mixer(cfg, spec, p, x, pos)
     h = L.apply_norm(cfg, p["norm2"], x)
     if spec["ffn"] == "moe":
-        b, s, d = h.shape
-        mm = L._mesh_axis("model")
-        if (cfg.attn_shard == "seq" and cfg.seq_residual and mm > 1
-                and s % mm == 0 and s > 1):
-            # sequence-parallel MoE: dispatch groups absorb the sequence
-            # shards (data-major, model-minor ordering matches the blocked
-            # residual layout) so capacity cumsums never cross devices —
-            # the giant dispatch all-reduces of the replicated layout
-            # cannot appear.  Capacity is budgeted per (batch, seq-shard)
-            # group; aux loss semantics unchanged (mean over groups).
-            hg = h.reshape(b * mm, s // mm, d)
-            hg = L._constrain(hg, ("data", "model") if b > 1 else "model",
-                              None, None)
-            y, aux = L.moe(cfg, p["moe"], hg)
-            y = y.reshape(b, s, d)
-        else:
-            y, aux = L.moe(cfg, p["moe"], h.reshape(b, s, d))
+        y, aux = L.moe(cfg, p["moe"], h)
     else:
         y = L.mlp(cfg, p["mlp"], h)
-    return L.constrain_residual(cfg, x + y), aux
+    return x + y, aux
 
 
 def _serve_ffn(cfg: ArchConfig, spec: Dict[str, str], p: Params, x, live,
@@ -172,8 +152,6 @@ def backbone(cfg: ArchConfig, params: Params, x, pos):
     """The training forward: x (B, S, d) -> (h (B, S, d), aux_loss), one
     scan over periods."""
     struct = period_structure(cfg)
-    np_ = n_periods(cfg)
-    x = L.constrain_residual(cfg, x)
 
     def period_run(x, period_params):
         a_total = jnp.zeros((), jnp.float32)
@@ -183,39 +161,16 @@ def backbone(cfg: ArchConfig, params: Params, x, pos):
         return x, a_total
 
     if cfg.remat:
-        period_run = jax.checkpoint(
-            period_run, policy=_remat_policy(cfg))
+        period_run = jax.checkpoint(period_run)
 
-    if cfg.static_unroll:
-        aux = jnp.zeros((), jnp.float32)
-        for per in range(np_):
-            pp = jax.tree.map(lambda l: l[per], params["positions"])
-            x, a = period_run(x, pp)
-            aux = aux + a
-    else:
-        def period_body(carry, period_params):
-            x, aux = carry
-            x, a = period_run(x, period_params)
-            return (x, aux + a), None
+    def period_body(carry, period_params):
+        x, aux = carry
+        x, a = period_run(x, period_params)
+        return (x, aux + a), None
 
-        (x, aux), _ = jax.lax.scan(
-            period_body, (x, jnp.zeros((), jnp.float32)),
-            params["positions"])
+    (x, aux), _ = jax.lax.scan(
+        period_body, (x, jnp.zeros((), jnp.float32)), params["positions"])
     return L.apply_norm(cfg, params["final_norm"], x), aux
-
-
-def run_periods(cfg: ArchConfig, body, carry, stacked, n: int):
-    """``lax.scan(body, carry, stacked)`` over ``n`` stacked periods or
-    layers, or an unrolled loop under ``cfg.static_unroll`` (dry-run)."""
-    if cfg.static_unroll:
-        outs = []
-        for i in range(n):
-            carry, y = body(carry, jax.tree.map(lambda l: l[i], stacked))
-            outs.append(y)
-        ys = (jax.tree.map(lambda *ls: jnp.stack(ls), *outs)
-              if outs and outs[0] is not None else None)
-        return carry, ys
-    return jax.lax.scan(body, carry, stacked)
 
 
 def run_stack(cfg: ArchConfig, positions, x, pos):
@@ -223,22 +178,11 @@ def run_stack(cfg: ArchConfig, positions, x, pos):
     a pipeline *stage* executes (launch/pipeline_prefill.py).  Aux losses
     are dropped — stages are inference-path."""
     struct = period_structure(cfg)
-    np_ = n_periods(cfg)
-    x = L.constrain_residual(cfg, x)
 
-    def period_run(x, period_params):
+    def body(x, period_params):
         for spec, p in zip(struct, period_params):
             x, _ = _position_block(cfg, spec, p, x, pos)
-        return x
-
-    if cfg.static_unroll:
-        for per in range(np_):
-            pp = jax.tree.map(lambda l: l[per], positions)
-            x = period_run(x, pp)
-        return x
-
-    def body(x, pp):
-        return period_run(x, pp), None
+        return x, None
 
     x, _ = jax.lax.scan(body, x, positions)
     return x
@@ -274,12 +218,7 @@ def chunked_ce_loss(cfg: ArchConfig, params: Params, h, labels,
         gold = jnp.take_along_axis(logits, ll[..., None], axis=-1)[..., 0]
         return tot + jnp.sum(lse - gold), None
 
-    if cfg.static_unroll:
-        tot = jnp.zeros((), jnp.float32)
-        for i in range(nc):
-            tot, _ = step(tot, (hc[i], lc[i]))
-    else:
-        tot, _ = jax.lax.scan(step, jnp.zeros((), jnp.float32), (hc, lc))
+    tot, _ = jax.lax.scan(step, jnp.zeros((), jnp.float32), (hc, lc))
     return tot / (b * s)
 
 
@@ -366,8 +305,8 @@ def decode_step(cfg: ArchConfig, params: Params, cache: Dict, tokens,
                 x, load = _serve_ffn(cfg, spec, p, x + y, live, p_stacked, i)
                 return (x, c), load
 
-            (x, new_c), load = run_periods(cfg, body, (x, c_stacked),
-                                           (p_xs, jnp.arange(np_)), np_)
+            (x, new_c), load = jax.lax.scan(body, (x, c_stacked),
+                                            (p_xs, jnp.arange(np_)))
         else:
             def body(x, per, spec=spec, p_stacked=p_stacked):
                 p, c, i = per
@@ -377,8 +316,8 @@ def decode_step(cfg: ArchConfig, params: Params, cache: Dict, tokens,
                 x, load = _serve_ffn(cfg, spec, p, x + y, live, p_stacked, i)
                 return x, ({"conv": nconv, "ssm": nssm}, load)
 
-            x, (new_c, load) = run_periods(
-                cfg, body, x, (p_xs, c_stacked, jnp.arange(np_)), np_)
+            x, (new_c, load) = jax.lax.scan(
+                body, x, (p_xs, c_stacked, jnp.arange(np_)))
         new_layers.append(new_c)
         if load is not None:
             loads.append(load)                          # (P, n_held)
@@ -399,7 +338,7 @@ def prefill(cfg: ArchConfig, params: Params, tokens, max_len: int,
     each layer handing on its K/V (attention) or (conv, ssm) state."""
     b, s = tokens.shape[:2]
     pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    x = L.constrain_residual(cfg, embed_tokens(cfg, params, tokens))
+    x = embed_tokens(cfg, params, tokens)
     struct = period_structure(cfg)
     extras: List[List] = [[] for _ in struct]
     loads = []
@@ -409,7 +348,6 @@ def prefill(cfg: ArchConfig, params: Params, tokens, max_len: int,
             p = jax.tree.map(lambda a: a[per], stacked)
             x, extra = _mixer(cfg, spec, p, x, pos, kv_out=True)
             x, load = _serve_ffn(cfg, spec, p, x, live, stacked, per)
-            x = L.constrain_residual(cfg, x)
             extras[pos_i].append(extra)
             if load is not None:
                 loads.append(load)

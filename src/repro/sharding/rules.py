@@ -1,7 +1,7 @@
 """Sharding rules: pytree-path -> PartitionSpec over the production mesh.
 
 Mesh axes: ``("data", "model")`` single-pod, ``("pod", "data", "model")``
-multi-pod.  Conventions (see DESIGN.md §8):
+multi-pod.  Conventions:
 
   * batch shards over ("pod","data"); vocab / heads / d_ff / experts /
     mamba-inner over "model";
@@ -9,7 +9,7 @@ multi-pod.  Conventions (see DESIGN.md §8):
     axis of weights over "data" (ZeRO-3 style) so params fit HBM;
   * optimizer moments are ZeRO-1 sharded over "data" for non-FSDP archs;
   * every rule checks divisibility and falls back to replication — GSPMD
-    *could* pad, but even sharding keeps the dry-run memory model honest.
+    *could* pad, but even sharding keeps per-device memory predictable.
 """
 
 from __future__ import annotations
@@ -78,23 +78,16 @@ def _param_rule(cfg: ArchConfig, path: str, shape: Tuple[int, ...],
     if parent == "moe":
         e = cfg.moe.held[1] if cfg.moe else 0            # experts held
         ep = _div(e, m)                                  # expert parallelism
-        # seq mode: tokens (dispatch groups) carry the model-axis
-        # parallelism, so non-EP expert weights must not shard a
-        # contraction dim over "model" (it would all-reduce the expert
-        # outputs) — replicate over model, FSDP over data if configured.
-        seq_repl = cfg.attn_shard == "seq" and not ep
         if leaf == "router":
             return spec(None, None)
         if leaf in ("w_gate", "w_up"):
             if ep:
                 return spec("model", fsdp, None)
-            return spec(None, fsdp, None) if seq_repl else \
-                spec(None, fsdp, "model")
+            return spec(None, fsdp, "model")
         if leaf == "w_down":
             if ep:
                 return spec("model", None, fsdp)
-            return spec(None, None, fsdp) if seq_repl else \
-                spec(None, "model", fsdp)
+            return spec(None, "model", fsdp)
         if leaf == "shared_gate":
             return spec(None, None)
     if parent == "mamba":

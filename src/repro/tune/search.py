@@ -16,9 +16,8 @@
     static rank), and only the top ``SearchSpace.shortlist`` of a round's
     survivors are *simulated* — the event engine is the cost model, but
     it is the funnel's last stage, not its first;
-  * **annealing skeleton** (after ``launch/hillclimb.py``'s
-    variant-walk) — each round expands the neighborhood of an incumbent
-    config; a worse simulated candidate can replace the incumbent with
+  * **annealing skeleton** — each round expands the neighborhood of an
+    incumbent config; a worse simulated candidate can replace the incumbent with
     probability ``exp(-rel_delta / T)`` under a geometrically decaying
     temperature, all drawn from the one seeded generator.
 

@@ -24,10 +24,9 @@ KV = {"bf16": dict(param_dtype="bfloat16", compute_dtype="bfloat16"),
       "int8": dict(kv_dtype="int8")}
 
 
-def _cfg(kv: str, static_unroll: bool = False):
+def _cfg(kv: str):
     # three periods of one attention layer each
-    return dataclasses.replace(smoke_config("qwen2-7b"), n_layers=3,
-                               static_unroll=static_unroll, **KV[kv])
+    return dataclasses.replace(smoke_config("qwen2-7b"), n_layers=3, **KV[kv])
 
 
 def _random_cache(cfg, seed: int):
@@ -93,27 +92,19 @@ def _blend_decode_step(cfg, params, cache, tokens):
     x = params["embed"][tokens][:, None]
     per = (params["positions"][0], cache["layers"][0])
     # the loop the program runs, so that bf16 rounds alike
-    if cfg.static_unroll:
-        news = []
-        for i in range(lm.n_periods(cfg)):
-            x, n = layer(x, jax.tree.map(lambda l: l[i], per))
-            news.append(n)
-        new = jax.tree.map(lambda *ls: jnp.stack(ls), *news)
-    else:
-        x, new = jax.lax.scan(layer, x, per)
+    x, new = jax.lax.scan(layer, x, per)
     h = L.apply_norm(cfg, params["final_norm"], x)[:, 0]
     logits = h.astype(jnp.float32) @ \
         lm.unembed_matrix(cfg, params).astype(jnp.float32).T
     return logits, {"layers": [new], "length": length + 1}
 
 
-@pytest.mark.parametrize("static_unroll", [False, True])
 @pytest.mark.parametrize("kv", sorted(KV))
-def test_decode_matches_blend(kv, static_unroll):
+def test_decode_matches_blend(kv):
     """Bitwise the blend's cache at every position of every slot and layer
     (the new rows included, none written past the end); logits within f32
     rounding."""
-    cfg = _cfg(kv, static_unroll)
+    cfg = _cfg(kv)
     model = build_model(cfg)
     params = model.init(jax.random.key(0))
     cache = _random_cache(cfg, seed=1)

@@ -4,7 +4,7 @@ shard_map execution matches the sequential reference.
 
 The execution test needs >1 device, so it runs in a subprocess with
 ``--xla_force_host_platform_device_count`` (tests themselves must see 1
-device — the dry-run is the only place 512 devices are forced).
+device; only subprocesses force more).
 """
 
 from __future__ import annotations

@@ -1,8 +1,13 @@
 """Sharding rules: every spec must be valid (sharded dims divisible by the
 mesh axis) for all 10 archs on both production meshes — checked abstractly,
-no devices needed."""
+no devices needed — and one real cell lowers and compiles on the multi-pod
+mesh (512 host devices, in a subprocess)."""
 
 from __future__ import annotations
+
+import os
+import subprocess
+import sys
 
 import jax
 import numpy as np
@@ -105,3 +110,43 @@ def test_long500k_cache_shards_sequence():
     specs = jax.tree.leaves(cspecs, is_leaf=lambda x: isinstance(x, P))
     kv = [s for s in specs if len(s) == 5]
     assert kv and all(s[2] == "data" for s in kv), kv
+
+
+def test_model_flops_6nd():
+    from repro.launch.specs import model_flops
+    cfg = get_arch("llama3.2-3b")
+    shape = SHAPES["train_4k"]
+    want = 6 * cfg.param_count() * shape.global_batch * shape.seq_len
+    assert model_flops(cfg, shape) == pytest.approx(want, rel=1e-6)
+    moe = get_arch("qwen3-moe-235b-a22b")
+    assert moe.active_param_count() < 0.2 * moe.param_count()
+    assert model_flops(moe, shape) == pytest.approx(
+        6 * moe.active_param_count() * shape.global_batch * shape.seq_len,
+        rel=1e-6)
+
+
+_COMPILE_CELL = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+import jax
+from repro.launch.mesh import make_production_mesh
+from repro.launch.specs import make_cell
+
+mesh = make_production_mesh(multi_pod=True)
+cell = make_cell("llama3.2-3b", "decode_32k", mesh)
+with jax.set_mesh(mesh):
+    jax.jit(cell.fn, in_shardings=cell.in_shardings,
+            donate_argnums=cell.donate).lower(*cell.args).compile()
+print("COMPILED", cell.kind, dict(mesh.shape))
+"""
+
+
+def test_one_real_cell_compiles_on_512_devices():
+    """Subprocess (device count must not leak into this pytest process)."""
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(root, "src")
+    r = subprocess.run([sys.executable, "-c", _COMPILE_CELL], env=env,
+                       capture_output=True, text=True, timeout=900, cwd=root)
+    assert "COMPILED decode {'pod': 2, 'data': 16, 'model': 16}" in r.stdout, \
+        r.stdout[-2000:] + r.stderr[-2000:]
